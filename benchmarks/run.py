@@ -18,6 +18,7 @@ from benchmarks import (fig1c_memory, fig4c_mha, fig6_latency, fig6_spatial,
                         fig6_temporal, fig7_efficiency, kernel_bench,
                         serve_bench, table1)
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig6a": fig6_spatial.run,
@@ -48,6 +49,7 @@ def main() -> None:
                     help="suites that support tracing (serve) export a "
                          "Chrome Trace Event JSON of their run here")
     args = ap.parse_args()
+    enable_compile_cache()
     names = list(SUITES) if not args.only else args.only.split(",")
     t0 = time.time()
     chunks = []

@@ -2,8 +2,8 @@
 
 Stdlib-only on purpose (``ast`` + friends): the linter never imports the
 code it scans, so it runs on a checkout with **no jax installed** — the CI
-lint job asserts exactly that — and behaves identically on the 0.4.37
-floor and latest. Rules live in ``repro.analysis.lint.rules`` and register
+lint job asserts exactly that — and behaves identically whatever jax is
+installed. Rules live in ``repro.analysis.lint.rules`` and register
 themselves via :func:`register`; adding a rule is one module with one
 class (see rules/__init__.py).
 

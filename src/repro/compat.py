@@ -1,157 +1,36 @@
-"""JAX version-compat shims (feature-detected, no version-string parsing).
+"""JAX wrappers that pin an argument the repo always passes the same way.
 
-Supported range: JAX 0.4.37 – 0.6.x. Policy (see ROADMAP.md "Open items"):
-every API that was renamed/added across that range is resolved HERE, once,
-by feature detection — call sites import from ``repro.compat`` and never
-touch ``hasattr`` themselves. Shims are detected at import time so a
-missing symbol fails loudly and early, not mid-kernel.
+The repo runs on one JAX, 0.9.0 (see ROADMAP.md "JAX version"); call sites
+use its spellings directly (``jax.set_mesh``, ``pltpu.CompilerParams``).
+A wrapper lives here only where it fixes an argument:
 
-Current shims:
-
-* ``tpu_compiler_params`` — ``pltpu.TPUCompilerParams`` (<= 0.4.x) was
-  renamed ``pltpu.CompilerParams`` (>= 0.5). Both take the same
-  ``dimension_semantics=...`` kwargs we use.
-* ``make_mesh`` — ``jax.make_mesh`` grew an ``axis_types=`` kwarg (and
-  ``jax.sharding.AxisType``) in 0.5. On older JAX every axis is already
-  implicitly Auto, so dropping the kwarg is semantics-preserving.
-* ``shard_map`` — promoted from ``jax.experimental.shard_map`` to
-  ``jax.shard_map`` in 0.6, and its replication-check kwarg was renamed
-  ``check_rep`` -> ``check_vma`` along the way. We always DISABLE the
-  check: the manual bodies the serving path maps contain ``pallas_call``
-  and explicit ``psum``s, which the checker cannot type.
+* ``make_mesh`` — every axis is ``AxisType.Auto``. ``jax.make_mesh``
+  defaults to ``Explicit`` axes, which the sharding rules
+  (``parallel/sharding.py``) and the serving TP plan do not use.
+* ``shard_map`` — the replication check is always off
+  (``check_vma=False``): the manual bodies the serving path maps contain
+  ``pallas_call`` and explicit ``psum``s, which the checker cannot type.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# --------------------------------------------------------------------------
-# Pallas TPU compiler params: CompilerParams (new) vs TPUCompilerParams (old)
-# --------------------------------------------------------------------------
-
-if hasattr(pltpu, "CompilerParams"):
-    _COMPILER_PARAMS_CLS = pltpu.CompilerParams
-else:
-    _COMPILER_PARAMS_CLS = pltpu.TPUCompilerParams
-
-
-def tpu_compiler_params(
-        *, dimension_semantics: Optional[Tuple[str, ...]] = None,
-        **kwargs: Any):
-    """Version-portable ``compiler_params=`` value for ``pl.pallas_call``."""
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = dimension_semantics
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
-
-# --------------------------------------------------------------------------
-# Mesh construction: axis_types= only exists on JAX >= 0.5
-# --------------------------------------------------------------------------
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    ``jax.set_mesh`` appeared in 0.5; on 0.4.x ``Mesh`` itself is the
-    context manager with the same enter/exit semantics.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-# jax.make_mesh has taken devices= across the whole supported range, but
-# feature-detect per the shim policy so a future rename fails HERE.
-_MAKE_MESH_HAS_DEVICES = \
-    "devices" in inspect.signature(jax.make_mesh).parameters
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with every axis explicitly Auto where the concept
-    exists (JAX >= 0.5) and implicitly Auto where it doesn't (0.4.x).
-    ``devices``: explicit device list (e.g. a replica's slice of
-    ``jax.devices()``); default lets JAX pick all local devices."""
-    kwargs: dict = {}
-    if devices is not None:
-        if not _MAKE_MESH_HAS_DEVICES:
-            import numpy as np
-            return jax.sharding.Mesh(
-                np.asarray(devices).reshape(tuple(axis_shapes)),
-                tuple(axis_names))
-        kwargs["devices"] = tuple(devices)
-    if HAS_AXIS_TYPE:
-        kwargs["axis_types"] = \
-            (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# --------------------------------------------------------------------------
-# shard_map: jax.shard_map (>= 0.6) vs jax.experimental.shard_map (0.4/0.5),
-# check_rep (old) vs check_vma (new) — always off, see module docstring
-# --------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _SHARD_MAP = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP
-
-_SHARD_MAP_CHECK_KWARG = next(
-    (k for k in ("check_rep", "check_vma")
-     if k in inspect.signature(_SHARD_MAP).parameters), None)
+    """``jax.make_mesh`` with every axis Auto. ``devices``: explicit
+    device list (e.g. a replica's slice of ``jax.devices()``); default
+    lets JAX pick all local devices."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=None if devices is None else tuple(devices))
 
 
 def shard_map(fn, mesh: jax.sharding.Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with the replication check disabled
-    (manual bodies here carry pallas_call + explicit psums, which the
-    checker rejects)."""
-    kwargs = {_SHARD_MAP_CHECK_KWARG: False} if _SHARD_MAP_CHECK_KWARG \
-        else {}
-    return _SHARD_MAP(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-# --------------------------------------------------------------------------
-# Canary self-test: re-run every shim's feature detection, report per shim
-# --------------------------------------------------------------------------
-
-def selftest() -> dict:
-    """Re-resolve every shim and report how each one landed.
-
-    The weekly ``compat-canary`` CI job runs this against JAX prereleases
-    (``jax>=0.7.0.dev0 --pre``) and posts the output in its step summary:
-    when upstream renames an API again, the summary names the SHIM that
-    needs a new branch, instead of leaving a mid-suite AttributeError to
-    bisect. Every value is ``"OK: <how it resolved>"`` or
-    ``"FAIL: <exception>"``; a FAIL here is always a missing detection
-    branch in this module, never a caller bug."""
-    checks = {
-        # construct the params object for real — the rename history is
-        # TPUCompilerParams -> CompilerParams, and a third name would
-        # resolve neither branch
-        "tpu_compiler_params": lambda: type(
-            tpu_compiler_params(dimension_semantics=("arbitrary",))
-        ).__name__,
-        "set_mesh": lambda: "jax.set_mesh" if hasattr(jax, "set_mesh")
-        else "Mesh-as-context-manager (0.4.x)",
-        "make_mesh.devices": lambda: "devices= kwarg"
-        if _MAKE_MESH_HAS_DEVICES else "Mesh(np.reshape) fallback",
-        "make_mesh.axis_types": lambda: "axis_types=Auto"
-        if HAS_AXIS_TYPE else "implicit Auto (0.4.x)",
-        "shard_map": lambda:
-            f"{_SHARD_MAP.__module__}.{_SHARD_MAP.__name__}",
-        "shard_map.check_kwarg": lambda:
-            _SHARD_MAP_CHECK_KWARG or "no replication-check kwarg",
-    }
-    report = {}
-    for name, probe in checks.items():
-        try:
-            report[name] = f"OK: {probe()}"
-        except Exception as e:                      # pragma: no cover
-            report[name] = f"FAIL: {type(e).__name__}: {e}"
-    return report
+    """``jax.shard_map`` with the replication check disabled (manual bodies
+    here carry pallas_call + explicit psums, which the checker rejects)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

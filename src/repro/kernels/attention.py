@@ -24,12 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 _NEG = -1e30
 
 
-def _mha_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
+def _mha_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref, *, n_kv: int, bq: int, bk: int, scale: float,
                 causal: bool, group: int):
     kv = pl.program_id(2)
@@ -46,9 +44,10 @@ def _mha_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
     kv_pos = kv * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    # per-sequence valid length arrives as data (page-aware kv_valid), so
-    # one compiled kernel serves every prompt length in a bucket
-    mask = kv_pos < valid_ref[0]
+    # per-sequence valid length arrives as scalar-prefetched data (page-
+    # aware kv_valid), so one compiled kernel serves every prompt length
+    # in a bucket
+    mask = kv_pos < valid_ref[pl.program_id(0)]
     if causal:
         # q rows are (group, rows) flattened; absolute position of row r
         # is (r % (bq//group)) + query block offset
@@ -137,28 +136,35 @@ def _mha(q: jax.Array, k: jax.Array, v: jax.Array, kv_valid: jax.Array, *,
 
     validf = jnp.repeat(kv_valid, KV)   # (B*KV,) — one row per b/kv program
 
-    out = pl.pallas_call(
-        functools.partial(
-            _mha_kernel, n_kv=n_kv, bq=bq_eff, bk=bk, scale=scale,
-            causal=causal, group=G),
+    # valid lengths ride in as a scalar-prefetch operand (SMEM), as the
+    # paged kernel's block table does: Mosaic refuses a rank-1 VMEM block
+    # that is not a multiple of the 128-lane tiling
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * KV, nq, n_kv),
         in_specs=[
-            pl.BlockSpec((1, bq_eff, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1,), lambda b, i, j: (b,)),
+            pl.BlockSpec((1, bq_eff, D), lambda b, i, j, vl: (b, i, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j, vl: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j, vl: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq_eff, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KV, nq * G * bq0, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq_eff, D),
+                               lambda b, i, j, vl: (b, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq_eff,), jnp.float32),
             pltpu.VMEM((bq_eff,), jnp.float32),
             pltpu.VMEM((bq_eff, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _mha_kernel, n_kv=n_kv, bq=bq_eff, bk=bk, scale=scale,
+            causal=causal, group=G),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * KV, nq * G * bq0, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, validf)
+    )(validf, qf, kf, vf)
 
     out = (out.reshape(B, KV, nq, G, bq0, D).transpose(0, 2, 4, 1, 3, 5)
            .reshape(B, Sqp, KV, G, D))

@@ -24,8 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, *, R: int, S: int, stride: int,
@@ -75,7 +74,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, bn: int = 128,
         out_specs=pl.BlockSpec((1, 1, OW, bn),
                                lambda n, oh, j: (n, oh, 0, j)),
         out_shape=jax.ShapeDtypeStruct((N, OH, OW, Kp), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(xp, wp)

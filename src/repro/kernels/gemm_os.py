@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
                  quant_scale: Optional[float]):
@@ -103,7 +101,7 @@ def gemm_os(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp)

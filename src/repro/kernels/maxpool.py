@@ -12,8 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _maxpool_kernel(x_ref, o_ref, *, R: int, S: int, stride: int, OW: int):
@@ -45,7 +44,7 @@ def maxpool2d(x: jax.Array, *, window: int = 2, stride: int = 2,
         in_specs=[pl.BlockSpec((1, H, W, C), lambda n, oh: (n, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, 1, OW, C), lambda n, oh: (n, oh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, OH, OW, C), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)

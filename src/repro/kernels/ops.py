@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the kernel layer.
 
-On a real TPU these dispatch to the Pallas kernels (compiled); everywhere
-else they run the kernels in interpret mode (bit-comparable semantics, the
-validation mode this repo uses on CPU) or fall back to the jnp reference.
+On the TPU backend these compile the Pallas kernels; on the CPU backend
+they run them in interpret mode (bit-comparable semantics, the validation
+mode this repo uses in its tests). Any other backend raises: a kernel
+that silently interprets on an accelerator hides the device.
 The model stack (repro.models) keeps pure-jnp paths so XLA SPMD handles
 sharding; the kernels are the per-chip compute layer a TPU deployment
 swaps in (see DESIGN.md "Kernel integration").
@@ -21,8 +22,17 @@ from repro.kernels import ref as _ref
 from repro.kernels import reshuffle as _reshuffle
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """``interpret=`` for the default backend: compiled on ``tpu``,
+    interpreted on ``cpu``, refused anywhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {backend!r}")
 
 
 def matmul(x: jax.Array, w: jax.Array, *,
@@ -30,7 +40,7 @@ def matmul(x: jax.Array, w: jax.Array, *,
            out_dtype=None) -> jax.Array:
     """Output-stationary 3D-blocked matmul (Voltra C1)."""
     return _gemm.gemm_os(x, w, block=block, out_dtype=out_dtype,
-                         interpret=not _on_tpu())
+                         interpret=_interpret())
 
 
 def quant_matmul(x: jax.Array, w: jax.Array, scale: float, *,
@@ -39,7 +49,7 @@ def quant_matmul(x: jax.Array, w: jax.Array, scale: float, *,
     """INT8 x INT8 -> INT32 accumulate -> fused quant epilogue -> INT8
     (Voltra C1 + C4)."""
     return _gemm.gemm_os(x, w, block=block, quant_scale=scale,
-                         interpret=not _on_tpu())
+                         interpret=_interpret())
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -47,7 +57,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               bq: int = 128, bk: int = 128) -> jax.Array:
     """Fused flash-MHA with on-the-fly K^T (Voltra C3/PDMA analogue)."""
     return _attention.mha(q, k, v, causal=causal, kv_valid=kv_valid,
-                          bq=bq, bk=bk, interpret=not _on_tpu())
+                          bq=bq, bk=bk, interpret=_interpret())
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -68,20 +78,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     the serving engine recycles to scratch — are skipped in-grid."""
     return _paged.paged_attention(q, k_pool, v_pool, block_table, lengths,
                                   kv_scale=kv_scale, window=window,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1) -> jax.Array:
     """Implicit-im2col Conv2D (6-D AGU analogue), SAME padding."""
-    return _conv.conv2d(x, w, stride=stride, interpret=not _on_tpu())
+    return _conv.conv2d(x, w, stride=stride, interpret=_interpret())
 
 
 def blocked_layout(x: jax.Array, cb: int = 128) -> jax.Array:
-    return _reshuffle.blocked_layout(x, cb, interpret=not _on_tpu())
+    return _reshuffle.blocked_layout(x, cb, interpret=_interpret())
 
 
 def transpose(x: jax.Array) -> jax.Array:
-    return _reshuffle.tiled_transpose(x, interpret=not _on_tpu())
+    return _reshuffle.tiled_transpose(x, interpret=_interpret())
 
 
 # re-export oracles for tests/benchmarks

@@ -70,8 +70,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 _NEG = -1e30
 
 
@@ -233,7 +231,7 @@ def _paged(q, k_pool, v_pool, block_table, lengths, *,
                           dequant=dequant, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, T * G, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, lengths, qg, k_pool, v_pool)

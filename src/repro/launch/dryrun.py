@@ -19,7 +19,6 @@ import traceback    # noqa: E402
 
 import jax          # noqa: E402
 
-from repro import compat                               # noqa: E402
 from repro.analysis import roofline as rl              # noqa: E402
 from repro.configs import (ARCHS, SHAPES, cell_runnable,  # noqa: E402
                            get_config)
@@ -56,7 +55,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         donate = ()
         if donate_cache and shape.kind == "decode":
             donate = (1,)            # alias the KV/state cache in->out
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                              donate_argnums=donate)
             lowered = jitted.lower(*jax.tree.map(lambda x: x, args))

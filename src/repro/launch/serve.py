@@ -28,6 +28,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import api
 from repro.runtime.drafter import DraftModelDrafter
@@ -38,13 +39,10 @@ from repro.runtime.serving import (DenseServingEngine, PagedServingEngine,
 from repro.runtime.trace import Tracer, set_default_tracer
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True, choices=ARCHS)
-    ap.add_argument("--smoke", action="store_true")
+def add_server_flags(ap: argparse.ArgumentParser) -> None:
+    """The flags that choose, size and configure the engine: what
+    ``build_server`` reads."""
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="decode policy: 0 = greedy (default), > 0 samples "
@@ -94,23 +92,13 @@ def main() -> None:
                          "engine only)")
     ap.add_argument("--route", choices=["hash", "least_loaded"],
                     default="hash", help="replica routing policy")
-    ap.add_argument("--trace", nargs="?", const="", default=None,
-                    metavar="TRACE.JSON",
-                    help="record per-tick spans and print the per-phase "
-                         "wall breakdown; with a filename, also export "
-                         "Chrome Trace Event JSON (open in Perfetto)")
-    args = ap.parse_args()
 
-    # install the tracer BEFORE engine construction: engines capture the
-    # process default at init
-    tracer = Tracer(enabled=True) if args.trace is not None else None
-    if tracer is not None:
-        set_default_tracer(tracer)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    print(f"[launch.serve] {cfg.name}: {cfg.param_count()/1e6:.1f}M params, "
-          f"{args.slots} slots")
-    params = api.init_params(cfg, jax.random.key(0))
+def build_server(cfg, params, args: argparse.Namespace):
+    """The engine for ``cfg`` as the ``add_server_flags`` flags in
+    ``args`` describe it. Returns ``(router, eng)``: with --replicas > 1
+    the router over the replicas and replica 0 (the telemetry shape
+    reference), else ``None`` and the engine."""
     sampling = SamplingParams(temperature=args.temperature,
                               top_k=args.top_k, top_p=args.top_p).validate()
     drafter = None
@@ -131,28 +119,53 @@ def main() -> None:
                     attn_impl=args.paged_attn,
                     prefix_cache=args.prefix_cache, spec_k=args.spec_k,
                     drafter=drafter, host_tier=args.host_tier)
-    router = None
     if args.replicas > 1:
         if args.engine == "dense":
             raise SystemExit("--replicas needs the paged engine")
         router = make_replicas(cfg, params, replicas=args.replicas,
                                model=args.shards, policy=args.route,
                                **paged_kw, **common)
-        eng = router.engines[0]          # telemetry shape reference
         print(f"[launch.serve] router: {args.replicas} replica(s) x "
               f"{args.shards} shard(s) over {len(jax.devices())} "
               f"device(s), policy {args.route}")
-    elif args.engine == "dense":
-        eng = DenseServingEngine(cfg, params, **common)
-    else:
-        mesh = None
-        if args.shards > 1:
-            from repro.launch.mesh import make_host_mesh
-            mesh = make_host_mesh(model=args.shards,
-                                  devices=jax.devices()[:args.shards])
-        builder = PagedServingEngine if args.engine == "paged" \
-            else ServingEngine
-        eng = builder(cfg, params, mesh=mesh, **paged_kw, **common)
+        return router, router.engines[0]
+    if args.engine == "dense":
+        return None, DenseServingEngine(cfg, params, **common)
+    mesh = None
+    if args.shards > 1:
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(model=args.shards,
+                              devices=jax.devices()[:args.shards])
+    builder = PagedServingEngine if args.engine == "paged" else ServingEngine
+    return None, builder(cfg, params, mesh=mesh, **paged_kw, **common)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", required=True, choices=ARCHS)
+    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--max-new", type=int, default=12)
+    ap.add_argument("--trace", nargs="?", const="", default=None,
+                    metavar="TRACE.JSON",
+                    help="record per-tick spans and print the per-phase "
+                         "wall breakdown; with a filename, also export "
+                         "Chrome Trace Event JSON (open in Perfetto)")
+    add_server_flags(ap)
+    args = ap.parse_args()
+    enable_compile_cache()
+
+    # install the tracer BEFORE engine construction: engines capture the
+    # process default at init
+    tracer = Tracer(enabled=True) if args.trace is not None else None
+    if tracer is not None:
+        set_default_tracer(tracer)
+
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    print(f"[launch.serve] {cfg.name}: {cfg.param_count()/1e6:.1f}M params, "
+          f"{args.slots} slots")
+    params = api.init_params(cfg, jax.random.key(0))
+    router, eng = build_server(cfg, params, args)
     print(f"[launch.serve] engine: {type(eng).__name__}")
     # production-shaped traffic: every request opens with the same system
     # prompt (what --prefix-cache shares), tails vary in length (what the
@@ -229,6 +242,7 @@ def main() -> None:
                       f"{ds['draft_ingested_tokens']:.0f} tokens ingested, "
                       f"{ds['draft_pool_rejects']:.0f} pool rejects")
     m = eng.metrics()
+    sampling = eng.default_params
     if not sampling.is_greedy:
         print(f"[launch.serve] decode policy: temperature "
               f"{sampling.temperature}, top_k {sampling.top_k}, top_p "

@@ -11,9 +11,9 @@ from __future__ import annotations
 
 import argparse
 
+import jax
 
-from repro import compat
-
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data.synthetic import DataConfig, SyntheticDataset
 from repro.launch.mesh import make_host_mesh
@@ -35,13 +35,14 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"[launch.train] {cfg.name}: {cfg.param_count()/1e6:.1f}M params")
     if args.model_parallel > 1:
         mesh = make_host_mesh(model=args.model_parallel)
         rules = Rules(mesh)
-        ctx = compat.set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
     else:
         rules, ctx = NO_RULES, None
 

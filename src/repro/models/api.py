@@ -45,6 +45,15 @@ def _build(cfg, mk: Maker, key=None) -> Dict[str, Any]:
 
 
 def init_params(cfg, key) -> Dict[str, Any]:
+    """Random weights from ``key``, made in one jitted program: run
+    eagerly, the layer-stacked init dispatches one small program per op
+    and materializes each weight stack in f32 before the cast (GBs at
+    published widths)."""
+    return _init_params(cfg, key)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params(cfg, key) -> Dict[str, Any]:
     mk = Maker("init", key, jnp.dtype(cfg.dtype))
     return _build(cfg, mk, key)
 

@@ -19,8 +19,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+
+from repro.compat import shard_map
 
 
 def gpipe(stage_fn: Callable, mesh: jax.sharding.Mesh, axis: str):
@@ -63,10 +64,9 @@ def gpipe(stage_fn: Callable, mesh: jax.sharding.Mesh, axis: str):
         return jax.lax.psum(buf, axis)
 
     return shard_map(
-        per_stage, mesh=mesh,
+        per_stage, mesh,
         in_specs=(P(axis), P()),       # params sharded by stage; xs replicated
-        out_specs=P(),
-        check_rep=False)
+        out_specs=P())
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:

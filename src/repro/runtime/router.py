@@ -44,7 +44,8 @@ def make_replicas(cfg, params, *, replicas: int = 1, model: int = 1,
     """Build R paged engines on disjoint ``model``-wide device slices and
     wrap them in a router. ``replicas * model`` must not exceed the
     visible device count; ``model == 1`` builds plain single-shard
-    engines (no mesh), so the single-device default keeps working."""
+    engines (no mesh), each with its weights, page pool and bookkeeping
+    placed on its own device, so the replicas never share a chip."""
     from repro.launch.mesh import make_host_mesh
     from repro.runtime.serving import PagedServingEngine
 
@@ -58,10 +59,17 @@ def make_replicas(cfg, params, *, replicas: int = 1, model: int = 1,
     engines = []
     for i in range(replicas):
         slice_ = devs[i * model:(i + 1) * model]
-        mesh = make_host_mesh(model=model, devices=slice_) \
-            if model > 1 else None
-        engines.append(PagedServingEngine(cfg, params, mesh=mesh,
-                                          **engine_kwargs))
+        if model > 1:
+            mesh = make_host_mesh(model=model, devices=slice_)
+            engines.append(PagedServingEngine(cfg, params, mesh=mesh,
+                                              **engine_kwargs))
+            continue
+        # one-device replica: commit the weights to the device and build
+        # the pool and bookkeeping under it; every program then follows
+        # its committed operands there
+        with jax.default_device(slice_[0]):
+            engines.append(PagedServingEngine(
+                cfg, jax.device_put(params, slice_[0]), **engine_kwargs))
     return ReplicaRouter(engines, policy=policy)
 
 

@@ -1625,6 +1625,19 @@ class PagedServingEngine(ServingMetricsMixin):
             self.win_table = self.win_table.at[
                 slot, base:base + n].set(SCRATCH_PAGE)
 
+    def _step_operands(self):
+        """The plain decode program's operands at the current state."""
+        pol = policy_operands(self._policy, self._rid_host,
+                              self._samp_idx, self.seed)
+        return (self.params, self.cache, self.block_table, self.win_table,
+                self.cur_tok, self.pos, self.live_mask, self.gen_cnt,
+                self.max_new_arr, pol)
+
+    def lower_step(self):
+        """The plain decode program lowered at the current state: its text
+        shows what runs on the device each decode tick."""
+        return self._step_fn.lower(*self._step_operands())
+
     def _step(self) -> List[Request]:
         """Advance every live slot: one device program, one host sync.
         With spec_k > 0 this is a speculative verify step emitting up to
@@ -1647,15 +1660,11 @@ class PagedServingEngine(ServingMetricsMixin):
             return []
         with tr.span("ensure_capacity"):
             evicted = self.ensure_decode_capacity()
-        pol = policy_operands(self._policy, self._rid_host,
-                              self._samp_idx, self.seed)
+        operands = self._step_operands()
         t0 = time.perf_counter()
         with tr.span("device_dispatch"):
             (self.cache, self.cur_tok, self.pos, self.gen_cnt,
-             self.live_mask, done_d, toks_d) = self._step_fn(
-                self.params, self.cache, self.block_table, self.win_table,
-                self.cur_tok, self.pos, self.live_mask, self.gen_cnt,
-                self.max_new_arr, pol)
+             self.live_mask, done_d, toks_d) = self._step_fn(*operands)
         with tr.span("host_sync"):
             # repro-lint: disable=host-sync — THE one blessed sync per step
             toks, done = jax.device_get((toks_d, done_d))

@@ -18,6 +18,19 @@ def _rand(key, shape, dtype):
     return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
 
+@pytest.mark.parametrize("backend,interpret",
+                         [("tpu", False), ("cpu", True), ("gpu", None)])
+def test_ops_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    # compiled on the TPU, interpreted on the CPU, refused anywhere else:
+    # no silent interpret-mode fallback on an accelerator
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
 # ---------------------------------------------------------------------------
 # gemm_os
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from repro import compat
     from repro.parallel.pipeline import bubble_fraction, gpipe
 
     mesh = jax.make_mesh((4,), ("stage",))
@@ -28,7 +27,7 @@ SCRIPT = textwrap.dedent("""
     xs = jax.random.normal(jax.random.key(1), (M, mb, d))
 
     piped = gpipe(stage_fn, mesh, "stage")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(piped)(params, xs)
 
     # sequential reference

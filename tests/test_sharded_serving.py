@@ -365,6 +365,22 @@ def test_router_replicas_match_baseline(policy):
     assert len(st["peak_pages_per_shard"]) == 2
 
 
+@needs2
+def test_router_replicas_own_their_devices():
+    """One-device replicas hold weights, pool and bookkeeping on their own
+    device, before and after serving (not all on the default device)."""
+    cfg = _cfg()
+    params = _params(cfg)
+    rr = make_replicas(cfg, params, replicas=2, slots=3, max_len=64,
+                       page_size=8)
+    rr.run_to_completion(_reqs(4))
+    for i, e in enumerate(rr.engines):
+        dev = jax.devices()[i]
+        for tree in (e.params, e.cache, e.block_table, e.pos):
+            assert {d for leaf in jax.tree.leaves(tree)
+                    for d in leaf.devices()} == {dev}
+
+
 @needs4
 @pytest.mark.slow
 def test_router_tp_replicas_compose():

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.analysis import hlo_cost, roofline
 from repro.launch.mesh import make_host_mesh
 from repro.parallel.sharding import Rules
@@ -55,7 +54,7 @@ def test_absent_axis_dropped():
 def test_cons_is_identity_math(mesh):
     r = Rules(mesh)
     x = jnp.arange(16.0).reshape(8, 2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda a: r.cons(a, "batch,"))(x)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
@@ -108,7 +107,7 @@ def test_collective_bytes_parsed():
     def f(x):
         return x.sum(0)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             f, in_shardings=r.sharding((n * 4, 8), "batch,"),
             out_shardings=jax.sharding.NamedSharding(mesh, P())).lower(
