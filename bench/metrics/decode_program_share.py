@@ -1,0 +1,10 @@
+"""Share of the device's program time in the traced window (the runs of
+every program, summed) spent in the decode program
+(``jit_step``), in percent."""
+from bench.readers import program_share
+
+PROGRAM = "jit_step"
+
+
+def read(run):
+    return program_share(run, PROGRAM)
