@@ -20,6 +20,11 @@ is replaced by a swap-in — pages AND recurrent state promote back from
 host RAM — but the scheduler's contract is unchanged: re-queue the
 evictee, resubmit later; ``tick`` additionally passes the queue snapshot
 to the engine's prefetch streamer so those H2D copies start a tick early.
+
+Each request's wait in the queue is an async ``queued`` interval on the
+engine's tracer, keyed by request id: it opens at ``add`` (or when a
+preempted request re-enters the queue) and closes at the start of the
+``submit`` that admits it; a refused ``submit`` leaves it open.
 """
 from __future__ import annotations
 
@@ -60,15 +65,23 @@ class Scheduler:
         note = getattr(self.engine, "note_arrival", None)
         if note is not None:
             note(req.rid)
+        self._trace.begin_async("queued", req.rid)
         self.pending.append(req)
+
+    @property
+    def _trace(self):
+        return getattr(self.engine, "trace", NULL_TRACER)
 
     def _admit(self) -> None:
         budget = self.max_admits_per_step
+        tr = self._trace
         while self.pending and (budget is None or budget > 0):
             req = self.pending[0]
             gen_before = len(req.generated)
+            t = tr.now() if tr else None
             if not self.engine.submit(req):
                 break                       # out of slots or pages
+            tr.end_async("queued", req.rid, ts=t)
             self.pending.popleft()
             self.admitted += 1
             # charge the admission budget only when a prefill actually
@@ -85,7 +98,7 @@ class Scheduler:
         prefetch hook hands the engine's host-tier streamer the queue
         snapshot so swap-ins and radix promotions for NEXT tick's
         admissions start their H2D copies under THIS tick's decode."""
-        tr = getattr(self.engine, "trace", NULL_TRACER)
+        tr = self._trace
         with tr.span("tick", tid="sched",
                      args={"pending": len(self.pending)} if tr else None):
             with tr.span("admit_loop", tid="sched"):
@@ -102,6 +115,7 @@ class Scheduler:
                 # front-to-back leaves the oldest evictee at the head of
                 # the queue.
                 for r in evicted:
+                    tr.begin_async("queued", r.rid)
                     self.pending.appendleft(r)
         self.steps += 1
 

@@ -170,7 +170,6 @@ class ServingMetricsMixin:
 
     * ``tick_wall_s`` — wall time of whole decode ticks (only ticks with
       live slots count, so an idle scheduler doesn't dilute the ratio);
-    * ``prefill_wall_s`` — wall time of successful admissions;
     * **temporal utilization** = ``step_wall_s / tick_wall_s``: the
       fraction of each decode tick spent in the device program (dispatch
       + the one host sync) rather than host-side bookkeeping, draft,
@@ -195,7 +194,6 @@ class ServingMetricsMixin:
         self.decoded_tokens = 0
         self.step_wall_s = 0.0        # device dispatch + sync, decode only
         self.tick_wall_s = 0.0        # whole decode ticks (live slots only)
-        self.prefill_wall_s = 0.0     # successful admissions (prefill wall)
         self.first_token_at: Dict[int, float] = {}
         self._arrival_at: Dict[int, float] = {}
         self._last_token_at: Dict[int, float] = {}
@@ -232,12 +230,8 @@ class ServingMetricsMixin:
         timed and traced."""
         self.note_arrival(req.rid)
         tr = self.trace
-        t0 = time.perf_counter()
         with tr.span("admit", args={"rid": req.rid} if tr else None):
-            ok = self._submit(req)
-        if ok:
-            self.prefill_wall_s += time.perf_counter() - t0
-        return ok
+            return self._submit(req)
 
     def step(self) -> List[Request]:
         """Advance every live slot (see the engine's ``_step``), timed and
@@ -291,7 +285,6 @@ class ServingMetricsMixin:
                                     if tpots else 0.0),
             "util.step_wall_s": self.step_wall_s,
             "util.tick_wall_s": tick,
-            "util.prefill_wall_s": self.prefill_wall_s,
             "util.temporal": self.step_wall_s / tick if tick > 0 else 0.0,
         }
         for ns, stats in (
@@ -319,7 +312,6 @@ class ServingMetricsMixin:
         self.decoded_tokens = 0
         self.step_wall_s = 0.0
         self.tick_wall_s = 0.0
-        self.prefill_wall_s = 0.0
         self.first_token_at.clear()
         self._arrival_at.clear()
         self._last_token_at.clear()
@@ -891,9 +883,11 @@ class PagedServingEngine(ServingMetricsMixin):
 
         model = self._wrap_sharded(model, 6)
 
-        def pf(params, cache, block_table, pos, cur_tok, live, gen,
-               max_new_arr, tokens, length, prefix_pages, prefix_len,
-               phys_tok, row_tok, row, slot, req_max_new, pol):
+        # its own program name, jit_pf_shared: the prefill readers match
+        # the jit_pf prefix, and a trace tells the two paths apart
+        def pf_shared(params, cache, block_table, pos, cur_tok, live, gen,
+                      max_new_arr, tokens, length, prefix_pages, prefix_len,
+                      phys_tok, row_tok, row, slot, req_max_new, pol):
             logits, new_cache = model(params, cache, tokens, length,
                                       prefix_pages, prefix_len, phys_tok,
                                       row_tok)
@@ -907,7 +901,7 @@ class PagedServingEngine(ServingMetricsMixin):
             return (new_cache, block_table, pos, cur_tok, live, gen,
                     max_new_arr, tok)
 
-        return pf
+        return pf_shared
 
     def _make_cow(self):
         """Device-side copy-on-write: duplicate one physical page (every
@@ -1243,7 +1237,8 @@ class PagedServingEngine(ServingMetricsMixin):
         self._rid_host[slot] = req.rid
         self._admit_counter += 1
         self._admit_seq[slot] = self._admit_counter
-        t = int(tok)
+        with self.trace.span("prefill_sync"):
+            t = int(tok)
         first = req.rid not in self.first_token_at
         req.generated.append(t)
         self._samp_idx[slot] = len(req.generated)
@@ -1660,7 +1655,8 @@ class PagedServingEngine(ServingMetricsMixin):
             return []
         with tr.span("ensure_capacity"):
             evicted = self.ensure_decode_capacity()
-        operands = self._step_operands()
+        with tr.span("operands"):
+            operands = self._step_operands()
         t0 = time.perf_counter()
         with tr.span("device_dispatch"):
             (self.cache, self.cur_tok, self.pos, self.gen_cnt,
@@ -1670,21 +1666,25 @@ class PagedServingEngine(ServingMetricsMixin):
             toks, done = jax.device_get((toks_d, done_d))
         self.step_wall_s += time.perf_counter() - t0
         self.decode_steps += 1
-        for i, r in enumerate(self.live):
-            if r is None:
-                continue
-            r.generated.append(int(toks[i]))
-            self._pos_host[i] += 1
-            self._samp_idx[i] += 1
-            self.decoded_tokens += 1
-            self._count_tokens(self._policy[i], 1)
-            self._note_emitted(r.rid)
-            if done[i]:
-                self._finish_slot(i)
-        if tr:
-            tr.counter("pool_pages", {
-                "allocated": float(self.alloc.allocated_pages),
-                "free": float(self.alloc.free_pages)})
+        with tr.span("emit"):
+            live = 0
+            for i, r in enumerate(self.live):
+                if r is None:
+                    continue
+                live += 1
+                r.generated.append(int(toks[i]))
+                self._pos_host[i] += 1
+                self._samp_idx[i] += 1
+                self.decoded_tokens += 1
+                self._count_tokens(self._policy[i], 1)
+                self._note_emitted(r.rid)
+                if done[i]:
+                    self._finish_slot(i)
+            if tr:
+                tr.counter("pool_pages", {
+                    "allocated": float(self.alloc.allocated_pages),
+                    "free": float(self.alloc.free_pages),
+                    "live": float(live)})
         return evicted
 
     def _step_speculative(self) -> List[Request]:
@@ -1732,14 +1732,16 @@ class PagedServingEngine(ServingMetricsMixin):
                 n_draft[s] = len(d)
                 self.spec_drafted += len(d)
                 self.spec_slot_steps += 1
-        pol = policy_operands(self._policy, self._rid_host,
-                              self._samp_idx, self.seed)
+        with tr.span("operands"):
+            pol = policy_operands(self._policy, self._rid_host,
+                                  self._samp_idx, self.seed)
+            tok_block_d = jnp.asarray(tok_block)
+            pos_d = jnp.asarray(self._pos_host, jnp.int32)
+            n_draft_d = jnp.asarray(n_draft, jnp.int32)
         with tr.span("device_dispatch"):
             self.cache, acc_d, emit_d = self._spec_fn(
                 self.params, self.cache, self.block_table, self.win_table,
-                jnp.asarray(tok_block),
-                jnp.asarray(self._pos_host, jnp.int32),
-                jnp.asarray(n_draft, jnp.int32), pol)
+                tok_block_d, pos_d, n_draft_d, pol)
         with tr.span("host_sync"):
             # repro-lint: disable=host-sync — the verify step's one sync
             accept, emit = jax.device_get((acc_d, emit_d))  # 1 host sync
@@ -2083,7 +2085,8 @@ class DenseServingEngine(ServingMetricsMixin):
                                                       {"tokens": toks})
         tok = self._sample_fn(last_logits, pol)[0]
         first = req.rid not in self.first_token_at
-        req.generated.append(int(tok))
+        with tr.span("prefill_sync"):
+            req.generated.append(int(tok))
         self._policy[slot] = pol_req
         self._rid_host[slot] = req.rid
         self._samp_idx[slot] = len(req.generated)
@@ -2114,8 +2117,9 @@ class DenseServingEngine(ServingMetricsMixin):
         if not any(r is not None for r in self.live):
             return []
         tr = self.trace
-        pol = policy_operands(self._policy, self._rid_host,
-                              self._samp_idx, self.seed)
+        with tr.span("operands"):
+            pol = policy_operands(self._policy, self._rid_host,
+                                  self._samp_idx, self.seed)
         t0 = time.perf_counter()
         with tr.span("device_dispatch"):
             logits, self.cache = self._decode(self.params, self.cache,
@@ -2129,21 +2133,22 @@ class DenseServingEngine(ServingMetricsMixin):
             jax.block_until_ready(toks)  # keep the sync inside the timer
         self.step_wall_s += time.perf_counter() - t0
         self.decode_steps += 1
-        for i, r in enumerate(self.live):
-            if r is None:
-                continue
-            t = int(toks[i])
-            r.generated.append(t)
-            self._samp_idx[i] += 1
-            self.decoded_tokens += 1
-            self._count_tokens(self._policy[i], 1)
-            self._note_emitted(r.rid)
-            if (t == self.eos_id or len(r.generated) >= r.max_new
-                    or int(self.pos[i]) >= self.max_len - 1):
-                r.done = True
-                self.live[i] = None
-                self._policy[i] = None
-                self._note_finished(r.rid)
+        with tr.span("emit"):
+            for i, r in enumerate(self.live):
+                if r is None:
+                    continue
+                t = int(toks[i])
+                r.generated.append(t)
+                self._samp_idx[i] += 1
+                self.decoded_tokens += 1
+                self._count_tokens(self._policy[i], 1)
+                self._note_emitted(r.rid)
+                if (t == self.eos_id or len(r.generated) >= r.max_new
+                        or int(self.pos[i]) >= self.max_len - 1):
+                    r.done = True
+                    self.live[i] = None
+                    self._policy[i] = None
+                    self._note_finished(r.rid)
         return []
 
     def has_live(self) -> bool:

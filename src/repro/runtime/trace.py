@@ -25,27 +25,35 @@ Design constraints, in order:
   *younger* in the buffer than everything it encloses — evicting oldest
   evicts innermost/earliest first. The dropped count is exported under
   ``otherData.dropped_events`` so a truncated trace says so.
-* **Host-clock only.** Timestamps are ``time.perf_counter_ns`` µs
-  relative to tracer creation. Device-side async work appears as the
-  host-visible dispatch/sync spans around it (the same one-host-sync
-  contract the engines already measure with ``step_wall_s``).
+* **On the profiler's timeline.** Timestamps are ``time.perf_counter_ns``
+  µs relative to tracer creation (``epoch_ns``, so a reader can turn them
+  back into ``time.perf_counter`` seconds). While enabled, each span also
+  enters a ``jax.profiler.TraceAnnotation`` named ``<tid>.<name>``
+  (``engine.decode_tick``, ``sched.tick``): a captured profile shows the
+  host phases on the same timeline as the device ops they dispatch and
+  wait for. The ``<tid>.`` prefix keeps them apart from the benchmark's
+  own annotations (``window``, ``tick``, ``step``, ...).
 
 Span taxonomy (tids group the timeline rows; see DESIGN.md
 "Observability" for the full map):
 
-* tid ``engine``  — ``admit`` (prefill path: ``prefix_match``,
-  ``prefill_dispatch``), ``decode_tick`` (``tier_drain``,
-  ``ensure_capacity``, ``draft``, ``device_dispatch``, ``host_sync``,
-  ``accept_rollback``), ``swap_in`` / ``swap_out`` / ``promote_match``.
+* tid ``engine``  — ``admit`` (prefill path: ``prefill_dispatch``,
+  ``prefill_sync``), ``decode_tick`` (``tier_drain``,
+  ``ensure_capacity``, ``draft``, ``operands``, ``device_dispatch``,
+  ``host_sync``, ``emit`` / ``accept_rollback``), ``swap_in`` /
+  ``swap_out`` / ``promote_match``; counter ``pool_pages`` (allocated,
+  free, live: the slots the step ran).
 * tid ``sched``   — ``tick`` (``admit_loop``, ``prefetch``).
 * tid ``tier``    — ``d2h_finalize``, ``h2d_demand_fetch`` (the copy-
   stream *stall*: a consumer whose prefetch never started), instants
   ``h2d_prefetch`` / ``h2d_hit``.
 * tid ``prefix``  — ``match``, ``evict``, instants ``insert``.
 * tid ``router``  — instants ``dispatch`` (per-replica routing).
-* cat ``request`` — async ``b``/``e`` pairs per request id (lifecycle:
-  enqueue -> done) with ``first_token`` instants, so per-request latency
-  reads directly off the timeline.
+* cat ``request`` — async ``b``/``e`` pairs per request id: ``request``
+  (lifecycle: enqueue -> done) with ``first_token`` instants, so
+  per-request latency reads directly off the timeline; ``queued``
+  (``Scheduler.add`` or a preemption -> the start of the admitting
+  ``submit``), the scheduler's queue wait.
 
 Usage::
 
@@ -60,7 +68,8 @@ pid/tid fields, per-tid span nesting, async pairing) and is exposed as
 ``python -m repro.runtime.trace --validate trace.json`` for the CI gate
 over the bench-smoke trace artifact.
 
-Pure host-side stdlib module: no jax imports, safe everywhere.
+A host-side stdlib module: jax is imported only by an enabled tracer's
+first span, so the module stays importable without it.
 """
 from __future__ import annotations
 
@@ -84,25 +93,45 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-class _Span:
-    """A live span: records one Chrome 'complete' (ph=X) event on exit."""
-    __slots__ = ("_tr", "name", "cat", "tid", "args", "_t0")
+_TraceAnnotation = None
 
-    def __init__(self, tr: "Tracer", name: str, cat: str, tid: int,
+
+def _annotation(label: str):
+    """The profiler annotation an enabled span enters (jax imported on
+    first use)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(label)
+
+
+class _Span:
+    """A live span: records one Chrome 'complete' (ph=X) event on exit,
+    and marks the profiler's timeline with a ``<tid>.<name>`` annotation
+    while open."""
+    __slots__ = ("_tr", "name", "cat", "tid", "args", "_t0", "_ann")
+
+    def __init__(self, tr: "Tracer", name: str, cat: str, tid: str,
                  args: Optional[Dict[str, Any]]):
         self._tr = tr
-        self.name, self.cat, self.tid, self.args = name, cat, tid, args
+        self.name, self.cat, self.args = name, cat, args
+        self.tid = tr._tid(tid)
+        self._ann = _annotation(f"{tid}.{name}")
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         tr = self._tr
-        ev = {"name": self.name, "cat": self.cat, "ph": "X",
-              "ts": (self._t0 - tr._epoch) // 1000,
-              "dur": (t1 - self._t0) // 1000,
+        # both ends floored to the µs on the tracer's clock, so a child
+        # never ends past its parent after rounding
+        ts = (self._t0 - tr.epoch_ns) // 1000
+        ev = {"name": self.name, "cat": self.cat, "ph": "X", "ts": ts,
+              "dur": (t1 - tr.epoch_ns) // 1000 - ts,
               "pid": tr.pid, "tid": self.tid}
         if self.args:
             ev["args"] = self.args
@@ -126,7 +155,7 @@ class Tracer:
         self.capacity = capacity
         self.pid = pid
         self.process_name = process_name
-        self._epoch = time.perf_counter_ns()
+        self.epoch_ns = time.perf_counter_ns()   # ts 0, perf_counter ns
         self._events: deque = deque(maxlen=capacity)
         self.events_recorded = 0             # lifetime (incl. dropped)
         self._tids: Dict[str, int] = {}      # thread name -> tid int
@@ -140,8 +169,9 @@ class Tracer:
         self._events.append(ev)
         self.events_recorded += 1
 
-    def _now(self) -> int:
-        return (time.perf_counter_ns() - self._epoch) // 1000
+    def now(self) -> int:
+        """The current time as an event ``ts``: µs since ``epoch_ns``."""
+        return (time.perf_counter_ns() - self.epoch_ns) // 1000
 
     def _tid(self, name: str) -> int:
         tid = self._tids.get(name)
@@ -156,7 +186,7 @@ class Tracer:
         single-threaded host code gives this for free)."""
         if not self.enabled:
             return NOOP_SPAN
-        return _Span(self, name, cat, self._tid(tid), args)
+        return _Span(self, name, cat, tid, args)
 
     def instant(self, name: str, *, tid: str = "engine",
                 cat: str = "serve",
@@ -165,7 +195,7 @@ class Tracer:
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
-              "ts": self._now(), "pid": self.pid, "tid": self._tid(tid)}
+              "ts": self.now(), "pid": self.pid, "tid": self._tid(tid)}
         if args:
             ev["args"] = args
         self._push(ev)
@@ -177,7 +207,7 @@ class Tracer:
         if not self.enabled:
             return
         self._push({"name": name, "cat": cat, "ph": "C",
-                    "ts": self._now(), "pid": self.pid,
+                    "ts": self.now(), "pid": self.pid,
                     "tid": self._tid(tid), "args": values})
 
     def begin_async(self, name: str, aid, *, cat: str = "request",
@@ -187,18 +217,21 @@ class Tracer:
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "b", "id": str(aid),
-              "ts": self._now(), "pid": self.pid,
+              "ts": self.now(), "pid": self.pid,
               "tid": self._tid("requests")}
         if args:
             ev["args"] = args
         self._push(ev)
 
     def end_async(self, name: str, aid, *, cat: str = "request",
-                  args: Optional[Dict[str, Any]] = None) -> None:
+                  args: Optional[Dict[str, Any]] = None,
+                  ts: Optional[int] = None) -> None:
+        """Close an async interval, now or at ``ts`` (a ``now()`` taken
+        earlier, when the interval's end is known only afterwards)."""
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "e", "id": str(aid),
-              "ts": self._now(), "pid": self.pid,
+              "ts": self.now() if ts is None else ts, "pid": self.pid,
               "tid": self._tid("requests")}
         if args:
             ev["args"] = args
@@ -212,10 +245,6 @@ class Tracer:
 
     def events(self) -> List[Dict[str, Any]]:
         return list(self._events)
-
-    def clear(self) -> None:
-        self._events.clear()
-        self.events_recorded = 0
 
     def phase_walls(self) -> Dict[str, Tuple[int, float]]:
         """Aggregate wall time by span name: ``{name: (count, secs)}``,

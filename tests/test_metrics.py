@@ -71,6 +71,9 @@ def test_metrics_key_set_identical_across_configs(driven):
     for ns in ("engine.", "latency.", "util.", "pool.", "spec.",
                "prefix.", "tier.", "shard."):
         assert any(k.startswith(ns) for k in ref), f"no {ns}* keys"
+    # the wall split the CI bench gate reads, and nothing more
+    assert {k for k in ref if k.startswith("util.")} == {
+        "util.step_wall_s", "util.tick_wall_s", "util.temporal"}
 
 
 def test_subsystem_stats_key_sets_stable(driven):
@@ -159,25 +162,114 @@ def test_engine_without_tracer_uses_null_tracer(driven):
         assert eng.trace is NULL_TRACER
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense"])
-def test_engine_run_produces_valid_trace(model, kind):
+@pytest.fixture(scope="module", params=["paged", "dense"])
+def traced(request, model):
+    """(kind, tracer, requests) of one scheduler-driven run with an
+    enabled tracer."""
     cfg, params = model
     tr = Tracer(enabled=True)
-    cls = PagedServingEngine if kind == "paged" else DenseServingEngine
+    cls = PagedServingEngine if request.param == "paged" \
+        else DenseServingEngine
     eng = cls(cfg, params, slots=SLOTS, max_len=MAX_LEN, tracer=tr)
-    reqs = _run(eng)
+    return request.param, tr, _run(eng)
+
+
+def _pairs(evs, name):
+    """[(id, ph)] of the async events called ``name``, in order."""
+    return [(e["id"], e["ph"]) for e in evs
+            if e.get("ph") in ("b", "e") and e["name"] == name]
+
+
+def test_engine_run_produces_valid_trace(traced):
+    kind, tr, reqs = traced
     obj = tr.to_dict()
     assert validate_trace(obj) == []
     evs = obj["traceEvents"]
     spans = {e["name"] for e in evs if e.get("ph") == "X"}
-    assert {"admit", "prefill_dispatch", "decode_tick", "device_dispatch",
-            "host_sync", "tick", "admit_loop"} <= spans
+    assert {"admit", "prefill_dispatch", "prefill_sync", "decode_tick",
+            "operands", "device_dispatch", "host_sync", "emit", "tick",
+            "admit_loop"} <= spans
     instants = {e["name"] for e in evs if e.get("ph") == "i"}
     assert "first_token" in instants
     # one async begin/end pair per request lifecycle
-    begins = [e["id"] for e in evs if e.get("ph") == "b"]
-    ends = [e["id"] for e in evs if e.get("ph") == "e"]
+    life = _pairs(evs, "request")
+    begins = [i for i, ph in life if ph == "b"]
+    ends = [i for i, ph in life if ph == "e"]
     assert sorted(begins) == sorted(ends) == [str(r.rid) for r in reqs]
     if kind == "paged":
         assert any(e.get("ph") == "C" and e["name"] == "pool_pages"
                    for e in evs)
+
+
+def _ticks(evs):
+    """Each decode_tick with the names of the engine spans directly
+    inside it, in order."""
+    engine = next(e["tid"] for e in evs if e.get("ph") == "M"
+                  and e["args"]["name"] == "engine")
+    spans = sorted(((e["ts"], -e["dur"], e["name"]) for e in evs
+                    if e.get("ph") == "X" and e["tid"] == engine))
+    out = []
+    for ts, neg, name in spans:
+        if name != "decode_tick":
+            continue
+        end = ts - neg
+        inner = [(s, s - n, m) for s, n, m in spans
+                 if ts <= s and s - n <= end and m != "decode_tick"]
+        # direct children: those no other span inside the tick encloses
+        kids = [m for s, e, m in inner
+                if not any(s2 <= s and e <= e2 and (s2, e2) != (s, e)
+                           for s2, e2, _ in inner)]
+        out.append(kids)
+    return out
+
+
+def test_decode_tick_children_are_the_phases(traced):
+    """Every decode tick is cut into the engine's host phases, nested in
+    it and in this order (the paged engine tops up pages first)."""
+    kind, tr, reqs = traced
+    ticks = _ticks(tr.to_dict()["traceEvents"])
+    want = (["ensure_capacity"] if kind == "paged" else []) + [
+        "operands", "device_dispatch", "host_sync", "emit"]
+    assert ticks and all(kids == want for kids in ticks), ticks
+    if kind == "paged":
+        live = [e["args"]["live"] for e in tr.events()
+                if e["ph"] == "C" and e["name"] == "pool_pages"]
+        assert len(live) == len(ticks)
+        assert set(live) <= set(range(1, SLOTS + 1)) and max(live) == SLOTS
+
+
+def test_queued_pairs_close_once_per_admission(traced):
+    kind, tr, reqs = traced
+    evs = tr.to_dict()["traceEvents"]
+    queued = _pairs(evs, "queued")
+    for r in reqs:
+        assert [ph for i, ph in queued if i == str(r.rid)] == ["b", "e"]
+    # each closes where its admission starts
+    admits = {e["args"]["rid"]: e["ts"] for e in evs
+              if e.get("ph") == "X" and e["name"] == "admit"}
+    ends = {e["id"]: e["ts"] for e in evs
+            if e.get("ph") == "e" and e["name"] == "queued"}
+    for r in reqs:
+        assert ends[str(r.rid)] <= admits[r.rid]
+
+
+def test_queued_pairs_reopen_after_preemption(model):
+    """4 pages of 4 tokens: decode growth preempts a request, which
+    queues again and closes a second ``queued`` pair when it resumes."""
+    cfg, params = model
+    tr = Tracer(enabled=True)
+    eng = PagedServingEngine(cfg, params, slots=2, max_len=32, page_size=4,
+                             num_pages=4, tracer=tr)
+    reqs = [Request(rid=0, prompt=[5, 4, 3, 2, 1, 6, 7], max_new=8),
+            Request(rid=1, prompt=[1, 2, 3, 4, 5, 6], max_new=8)]
+    sched = Scheduler(eng)
+    for r in reqs:
+        sched.add(r)
+    sched.drain(max_steps=400)
+    assert sched.preempted >= 1
+    obj = tr.to_dict()
+    assert validate_trace(obj) == []
+    queued = _pairs(obj["traceEvents"], "queued")
+    for r in reqs:
+        mine = [ph for i, ph in queued if i == str(r.rid)]
+        assert mine == ["b", "e"] * (1 + r.preemptions)

@@ -1,10 +1,17 @@
-"""Tracer unit tests: Chrome Trace Event schema, ring-buffer bounds, and
-the disabled-tracer no-op contract (ISSUE 8). Pure host-side — no jax."""
+"""Tracer unit tests: Chrome Trace Event schema, ring-buffer bounds, the
+disabled-tracer no-op contract, and an enabled tracer's spans on the JAX
+profiler's timeline."""
+import glob
 import json
+import sys
+from pathlib import Path
 
+from repro.runtime import trace as trace_mod
 from repro.runtime.trace import (NOOP_SPAN, NULL_TRACER, Tracer,
                                  default_tracer, percentile,
                                  set_default_tracer, validate_trace)
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 # -- schema / export ------------------------------------------------------
@@ -109,6 +116,79 @@ def test_disabled_tracer_allocates_nothing():
     assert tr.events() == []
     assert tr.events_recorded == 0
     assert NULL_TRACER.span("z") is NOOP_SPAN
+
+
+def test_disabled_tracer_enters_no_profiler_annotation(monkeypatch):
+    labels = []
+    real = trace_mod._annotation
+
+    def counting(label):
+        labels.append(label)
+        return real(label)
+
+    monkeypatch.setattr(trace_mod, "_annotation", counting)
+    off = Tracer(enabled=False)
+    with off.span("decode_tick"):
+        with off.span("tick", tid="sched"):
+            pass
+    assert labels == []
+    on = Tracer(enabled=True)
+    with on.span("decode_tick"):
+        with on.span("tick", tid="sched"):
+            pass
+    assert labels == ["engine.decode_tick", "sched.tick"]
+
+
+def test_enabled_spans_land_in_the_profiler_trace(tmp_path):
+    """Under the JAX profiler an enabled tracer's spans are host events
+    named ``<tid>.<name>``, never one of the names the benchmark's trace
+    reader keeps for its own spans."""
+    import jax
+    from jax.profiler import ProfileData
+    sys.path.insert(0, str(ROOT))
+    from bench import xplane
+    tr = Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("tick", tid="sched"):
+            with tr.span("decode_tick"):
+                with tr.span("step"):      # a bench span name, prefixed
+                    jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"sched.tick", "engine.decode_tick", "engine.step"} <= names
+    assert not names & (set(xplane.HOST_SPANS) | {xplane.WINDOW})
+
+
+def test_rounding_keeps_children_inside_parents(monkeypatch):
+    """Both ends of a span are floored to the µs, so a child that ends in
+    its parent's last microsecond still nests."""
+    ns = iter([0, 900, 1000, 2000, 2000])
+    monkeypatch.setattr(trace_mod.time, "perf_counter_ns", lambda: next(ns))
+    tr = Tracer(enabled=True)
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    inner, outer = tr.events()
+    assert (outer["ts"], outer["dur"]) == (0, 2)
+    assert (inner["ts"], inner["dur"]) == (1, 1)
+    assert validate_trace(tr.to_dict()) == []
+
+
+def test_epoch_and_end_async_at_a_given_time():
+    tr = Tracer(enabled=True)
+    t = tr.now()
+    tr.begin_async("queued", 3)
+    tr.end_async("queued", 3, ts=t)
+    b, e = tr.events()
+    assert e["ts"] == t <= b["ts"]
+    # events convert back to time.perf_counter seconds through epoch_ns
+    import time
+    assert abs(tr.epoch_ns * 1e-9 + t * 1e-6 - time.perf_counter()) < 1.0
 
 
 def test_default_tracer_install_and_restore():
