@@ -33,7 +33,7 @@ decode kernel that performs the block-table gather inside the kernel
 * ``attn_peak_live_bytes`` — peak live bytes of the per-layer decode-
   attention KV working set: the gather path materializes the full
   (B, n_blocks*page, KV, D) K and V scratch every layer; the kernel path
-  keeps one (page, KV, D) K/V tile resident (the paper's separated-vs-
+  keeps two multi-page K and V blocks resident (the paper's separated-vs-
   shared memory access cost, measured at the serving level)
 
 **shared-prefix** — every request opens with the same system prompt
@@ -211,10 +211,11 @@ def _attn_peak_live_bytes(cfg, engine) -> int:
     kv, hd = cfg.kv_heads, cfg.resolved_head_dim
     if isinstance(engine, PagedServingEngine) \
             and engine.attn_impl == "kernel":
-        # one K + one V page tile resident per kernel program, in the
-        # pool's storage dtype (int8 pools dequantize inside the kernel)
+        # two K + two V blocks resident (double buffered), in the pool's
+        # storage dtype (int8 pools dequantize inside the kernel)
         itemsize = 1 if cfg.kv_cache_dtype == "int8" else 2
-        return 2 * engine.page_size * kv * hd * itemsize
+        tokens = engine._kv_block_tokens or engine.page_size
+        return 4 * tokens * kv * hd * itemsize
     # dense lanes / dense gather: the whole (B, max_len, KV, D) K and V,
     # materialized DEQUANTIZED to the 2-byte activation dtype
     # (layers.kv_dequant) regardless of the cache storage dtype. A dense

@@ -8,7 +8,7 @@
   maxpool     — Sec. II-E maxpool unit (arbitrary windows, lane-parallel)
   paged_attention — §III shared-memory streamers at serving time: flash-
                 decode with the block-table gather inside the kernel
-                (scalar-prefetched table, page-granular KV tiles)
+                (scalar-prefetched table, multi-page KV blocks)
   ops         — public jit'd wrappers (TPU: compiled; CPU: interpret)
   ref         — pure-jnp oracles (the correctness contract for tests)
 """

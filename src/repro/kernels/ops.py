@@ -65,8 +65,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     kv_scale: Optional[float] = None,
                     window: int = 0) -> jax.Array:
     """Flash-decode over a paged KV pool: the block-table indirection runs
-    INSIDE the kernel (scalar-prefetched table, page-granular KV tiles,
-    online softmax), so the per-layer dense gather of the PR-1 serving
+    INSIDE the kernel (scalar-prefetched table, double-buffered multi-page
+    KV blocks, online softmax), so the per-layer dense gather of the PR-1 serving
     path never materializes (Voltra's shared-memory streamers; DESIGN.md
     "Paged attention"). q: (B, H, D) single-token decode, or (B, T, H, D)
     T-token query block (speculative verify — in-sweep causal masking,

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.paged_attention import pool_rows
 from repro.parallel.sharding import NO_RULES, Rules
 
 # ---------------------------------------------------------------------------
@@ -396,10 +397,10 @@ def attention_decode(cfg, p, x, cache, pos, *, rules: Rules = NO_RULES,
     scratch page — their logits are only ever produced to be discarded by
     the engine's max_len stop); attention then runs the block-table
     indirection INSIDE the flash-decode kernel (ops.paged_attention), one
-    page tile at a time, causally masked row-by-row against pos + T — so
-    pool garbage (scratch page, not-yet-written tail) never contributes
-    probability mass and the dense (B, n_blocks*page, KV, D) gathered KV
-    never materializes. T > 1 is the speculative-verify block (engine
+    multi-page KV block at a time, causally masked row-by-row against
+    pos + T — so pool garbage (scratch page, not-yet-written tail) never
+    contributes probability mass and the dense (B, n_blocks*page, KV, D)
+    gathered KV never materializes. T > 1 is the speculative-verify block (engine
     spec_k): K drafted tokens + the current one score in ONE page sweep.
     window > 0 in paged mode is a sliding-window layer (hybrid
     local_attn) on the paged layout: logical block index still means
@@ -440,8 +441,16 @@ def attention_decode(cfg, p, x, cache, pos, *, rules: Rules = NO_RULES,
                                 jnp.minimum(blk, n_blk - 1), axis=1),
             0)
         off = pos_t % page
-        ck = cache["k"].at[phys, off].set(kv_quant(cfg, k))
-        cv = cache["v"].at[phys, off].set(kv_quant(cfg, v))
+        # the kernel reads the pool through its row view: write through
+        # the same view, so XLA keeps one layout between the two
+        rows = (cache["k"].shape if cfg.paged_attn_impl == "gather"
+                else pool_rows(cache["k"].shape))
+
+        def write(pool, x):
+            return pool.reshape(rows).at[phys, off].set(
+                kv_quant(cfg, x).reshape(B, T, *rows[2:])).reshape(pool.shape)
+
+        ck, cv = write(cache["k"], k), write(cache["v"], v)
         if cfg.paged_attn_impl == "gather":
             # PR-1 baseline: dense per-layer pool gather (the "separated
             # memory" anti-pattern; kept only for serve_bench comparison).

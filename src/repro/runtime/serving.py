@@ -86,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.paged_attention import pages_per_block
 from repro.models import api
 from repro.models import transformer as tfm
 from repro.parallel.sharding import NO_RULES, Rules
@@ -496,6 +497,18 @@ class PagedServingEngine(ServingMetricsMixin):
             # owns its GQA groups' slice of EVERY page
             self._cache_specs = self.tp.cache_specs(cfg, self.cache)
             self.cache = self.tp.put(self.cache, self._cache_specs)
+        # tokens per KV block the attention kernel streams on a full
+        # layer, from the pool shape each shard holds (the tracer's
+        # kv_blocks series); None where no full layer runs the kernel
+        self._kv_block_tokens = None
+        if self.has_full and attn_impl == "kernel":
+            kv = cfg.kv_heads
+            if self.tp is not None and "kv_heads" in self.tp.sharded_axes:
+                kv //= self.tp.model_shards
+            self._kv_block_tokens = page_size * pages_per_block(
+                page_size, kv, cfg.resolved_head_dim,
+                jnp.dtype(cfg.kv_cache_dtype).itemsize,
+                min(self.max_blocks, usable + 1))
         self.block_table = jnp.zeros((slots, self.max_blocks), jnp.int32)
         # sliding-window block table: logical block j still means absolute
         # positions [j*page, (j+1)*page), but entries that slid below the
@@ -625,8 +638,11 @@ class PagedServingEngine(ServingMetricsMixin):
             # (`pol`), so greedy and sampled rows share this trace.
             # repro-lint: disable=retrace-hazard — counting traces IS the point
             self.step_traces += 1
+            # a dead slot keeps its last request's position, but its
+            # table is all scratch: decode it at position 0, so the
+            # attention kernel streams one page for it, not that length
             logits, cache = decode(params, cache, block_table, win_table,
-                                   cur_tok, pos)
+                                   cur_tok, jnp.where(live, pos, 0))
             toks = sample_rows(logits[..., : cfg.vocab], pol)
             livei = live.astype(jnp.int32)
             pos2 = pos + livei
@@ -1667,7 +1683,13 @@ class PagedServingEngine(ServingMetricsMixin):
         self.step_wall_s += time.perf_counter() - t0
         self.decode_steps += 1
         with tr.span("emit"):
-            live = 0
+            live, series = 0, {}
+            if tr and self._kv_block_tokens:
+                # blocks each layer's kernel streamed: cdiv(pos + 1, block)
+                # per live slot, at the positions the step ran
+                series["kv_blocks"] = float(sum(
+                    -(-(self._pos_host[i] + 1) // self._kv_block_tokens)
+                    for i, r in enumerate(self.live) if r is not None))
             for i, r in enumerate(self.live):
                 if r is None:
                     continue
@@ -1684,7 +1706,7 @@ class PagedServingEngine(ServingMetricsMixin):
                 tr.counter("pool_pages", {
                     "allocated": float(self.alloc.allocated_pages),
                     "free": float(self.alloc.free_pages),
-                    "live": float(live)})
+                    "live": float(live), **series})
         return evicted
 
     def _step_speculative(self) -> List[Request]:
@@ -1736,7 +1758,8 @@ class PagedServingEngine(ServingMetricsMixin):
             pol = policy_operands(self._policy, self._rid_host,
                                   self._samp_idx, self.seed)
             tok_block_d = jnp.asarray(tok_block)
-            pos_d = jnp.asarray(self._pos_host, jnp.int32)
+            pos_d = jnp.asarray([p if r is not None else 0 for p, r in
+                                 zip(self._pos_host, self.live)], jnp.int32)
             n_draft_d = jnp.asarray(n_draft, jnp.int32)
         with tr.span("device_dispatch"):
             self.cache, acc_d, emit_d = self._spec_fn(

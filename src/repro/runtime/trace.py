@@ -42,7 +42,8 @@ Span taxonomy (tids group the timeline rows; see DESIGN.md
   ``ensure_capacity``, ``draft``, ``operands``, ``device_dispatch``,
   ``host_sync``, ``emit`` / ``accept_rollback``), ``swap_in`` /
   ``swap_out`` / ``promote_match``; counter ``pool_pages`` (allocated,
-  free, live: the slots the step ran).
+  free, live: the slots the step ran; kv_blocks: the KV blocks the
+  attention kernel streamed per layer).
 * tid ``sched``   — ``tick`` (``admit_loop``, ``prefetch``).
 * tid ``tier``    — ``d2h_finalize``, ``h2d_demand_fetch`` (the copy-
   stream *stall*: a consumer whose prefetch never started), instants

@@ -2,6 +2,7 @@
 configuration, sane latency/utilization numbers, a single warm-up reset
 point, and engine-driven traces that pass schema validation."""
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_smoke_config
@@ -236,6 +237,41 @@ def test_decode_tick_children_are_the_phases(traced):
                 if e["ph"] == "C" and e["name"] == "pool_pages"]
         assert len(live) == len(ticks)
         assert set(live) <= set(range(1, SLOTS + 1)) and max(live) == SLOTS
+
+
+def test_kv_blocks_counts_the_kernels_blocks(model, monkeypatch):
+    """With the tracer on, each decode tick's ``pool_pages`` counter has a
+    ``kv_blocks`` series: the KV blocks the attention kernel streams per
+    layer, sum over live slots of cdiv(length, ppb * page)."""
+    from repro.kernels import paged_attention as pa
+    cfg, params = model
+    page = 4
+    page_bytes = (page * cfg.kv_heads * cfg.resolved_head_dim
+                  * jnp.dtype(cfg.kv_cache_dtype).itemsize)
+    monkeypatch.setattr(pa, "_BLOCK_BYTES", 2 * page_bytes)   # ppb = 2
+    tr = Tracer(enabled=True)
+    eng = PagedServingEngine(cfg, params, slots=SLOTS, max_len=MAX_LEN,
+                             page_size=page, tracer=tr)
+    want, step = [], eng._step
+
+    def counted_step():
+        # the kernel's length of a live slot: every token but the one
+        # this step produces
+        lens = [len(r.prompt) + len(r.generated) for r in eng.live if r]
+        if lens:
+            want.append(sum(-(-n // (2 * page)) for n in lens))
+        return step()
+
+    monkeypatch.setattr(eng, "_step", counted_step)
+    reqs = _reqs(3, max_new=10)
+    sched = Scheduler(eng)
+    for r in reqs:
+        sched.add(r)
+    sched.drain(max_steps=200)
+    got = [e["args"]["kv_blocks"] for e in tr.events()
+           if e["ph"] == "C" and e["name"] == "pool_pages"]
+    assert got == want
+    assert max(want) > SLOTS          # some slot streams two blocks
 
 
 def test_queued_pairs_close_once_per_admission(traced):

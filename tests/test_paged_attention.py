@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.runtime.kv_cache import SCRATCH_PAGE, PageAllocator
 
 
@@ -443,6 +444,110 @@ def test_property_windowed_matches_ref(page, n_blocks, t_rows, window,
                                    window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-3, atol=3e-3)
+
+
+# ---------------------------------------------------------------------------
+# Multi-page KV blocks: ppb pages stream per block, double-buffered across
+# blocks and requests, nothing fetched past a request's live keys
+# ---------------------------------------------------------------------------
+
+
+def _blocked(q, kp, vp, table, lengths, ppb, **kw):
+    """The kernel at an explicit pages-per-block (the public entry point
+    derives it from the pool's shapes)."""
+    q4 = q if q.ndim == 4 else q[:, None]
+    out = pa._paged(q4, kp, vp, table, jnp.asarray(lengths, jnp.int32),
+                    kv_scale=kw.get("kv_scale"), window=kw.get("window", 0),
+                    ppb=ppb, interpret=True)
+    return out if q.ndim == 4 else out[:, 0]
+
+
+def _poison(kp, vp, table, lengths, value):
+    """Set every pool row no request may read to ``value``: the scratch
+    page, the unwritten tail of each last page, and unowned pages."""
+    page = kp.shape[1]
+    owned = {int(p_) for p_ in np.asarray(table).ravel()}
+    dead = [p_ for p_ in range(kp.shape[0]) if p_ not in owned]
+    kp2 = kp.at[SCRATCH_PAGE].set(value).at[jnp.asarray(dead)].set(value)
+    vp2 = vp.at[SCRATCH_PAGE].set(value).at[jnp.asarray(dead)].set(value)
+    for b, n in enumerate(lengths):
+        blk, off = n // page, n % page
+        if off:
+            kp2 = kp2.at[table[b, blk], off:].set(value)
+            vp2 = vp2.at[table[b, blk], off:].set(value)
+    return kp2, vp2
+
+
+@pytest.mark.parametrize("ppb", [1, 3, 8])
+def test_block_sizes_match_ref_and_ignore_poison(ppb):
+    """Every block size, including one page and the whole table: lengths
+    of 1, exactly one block, one block + 1 and max_len side by side with
+    a dead slot (length 0) and an engine-style dead slot (all-scratch
+    table, length 1). Live rows match the oracle; a pool whose
+    unreadable rows are NaN or Inf gives bit-identical outputs."""
+    B, H, KV, D, page, n_blocks = 6, 4, 2, 16, 8, 8
+    bk, max_len = ppb * page, n_blocks * page
+    lengths = [1, bk, min(bk + 1, max_len), max_len, 0, 1]
+    q, kp, vp, table, _, _ = _paged_case(
+        jax.random.key(60 + ppb), B, H, KV, D, page, n_blocks,
+        lengths[:4] + [0, 0], shuffle_key=jax.random.key(61))
+    got = _blocked(q, kp, vp, table, lengths, ppb)
+    want = ref.paged_attention_ref(q, kp, vp, table, jnp.asarray(lengths))
+    np.testing.assert_allclose(np.asarray(got[:4]), np.asarray(want[:4]),
+                               rtol=3e-3, atol=3e-3)
+    assert np.all(np.asarray(got[4]) == 0)
+    assert np.all(np.isfinite(np.asarray(got)))
+    for value in (jnp.nan, jnp.inf):
+        kp2, vp2 = _poison(kp, vp, table, lengths, value)
+        got2 = _blocked(q, kp2, vp2, table, lengths, ppb)
+        np.testing.assert_array_equal(np.asarray(got[:4]),
+                                      np.asarray(got2[:4]))
+
+
+@pytest.mark.parametrize("ppb", [2, 4])
+@pytest.mark.parametrize("variant", ["T3", "int8", "window", "window-T2"])
+def test_multi_page_variants_match_ref(variant, ppb):
+    """Speculative T-row blocks, int8 pools and sliding windows through
+    several multi-page blocks per request, on a NaN-poisoned pool (with
+    a window, the recycled lead blocks point at the NaN scratch page)."""
+    B, H, KV, D, page, n_blocks = 3, 4, 2, 16, 4, 12
+    T = {"T3": 3, "window-T2": 2}.get(variant, 1)
+    window = 6 if variant.startswith("window") else 0
+    lengths = [T + 1, 2 * ppb * page + 1, n_blocks * page]
+    q, kp, vp, table, _, _ = _paged_case(
+        jax.random.key(70 + ppb), B, T * H, KV, D, page, n_blocks, lengths,
+        shuffle_key=jax.random.key(71))
+    q = q.reshape(B, T, H, D) if T > 1 else q
+    kw = {"window": window}
+    if variant == "int8":
+        kw["kv_scale"] = 8.0
+        kp = jnp.clip(jnp.round(kp * 127 / 8.0), -127, 127).astype(jnp.int8)
+        vp = jnp.clip(jnp.round(vp * 127 / 8.0), -127, 127).astype(jnp.int8)
+    want = ref.paged_attention_ref(q, kp, vp, table, jnp.asarray(lengths),
+                                   **kw)
+    if variant != "int8":              # int8 holds no NaN
+        kp, vp = _poison(kp, vp, table, lengths, jnp.nan)
+    if window:
+        table = np.asarray(table).copy()
+        for b, n in enumerate(lengths):
+            table[b, :max(0, n - T - window + 1) // page] = SCRATCH_PAGE
+        table = jnp.asarray(table)
+    got = _blocked(q, kp, vp, table, lengths, ppb, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=3e-3, atol=3e-3)
+
+
+def test_pages_per_block_from_shapes():
+    """ppb targets a fixed K block size from page x KV x D x itemsize,
+    capped by the table width and by a window's pages."""
+    blk = pa._BLOCK_BYTES
+    assert pa.pages_per_block(16, 2, 128, 2, 128) == blk // (16 * 2 * 128 * 2)
+    assert pa.pages_per_block(16, 8, 64, 2, 256) == blk // (16 * 8 * 64 * 2)
+    assert pa.pages_per_block(16, 2, 128, 1, 128) == \
+        2 * pa.pages_per_block(16, 2, 128, 2, 128)
+    assert pa.pages_per_block(16, 2, 128, 2, 3) == 3
+    assert pa.pages_per_block(16, 1, 256, 2, 128, window=40) == 3
+    assert pa.pages_per_block(16, 64, 256, 4, 128) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -77,6 +77,9 @@ CASES = {
     "paged-qwen-T1": lambda: _paged_case("qwen2.5-3b", 1),
     "paged-qwen-T4": lambda: _paged_case("qwen2.5-3b", 4),
     "paged-qwen-int8": lambda: _paged_case("qwen2.5-3b", 1, jnp.int8),
+    # granite-3-2b decode: 32 q heads over 8 KV heads x 64 dims (two heads
+    # share a 128-lane slab row)
+    "paged-granite-T1": lambda: _paged_case("granite-3-2b", 1),
     # recurrentgemma-9b local attention: 1 KV head x 256 dims, window 2048
     "paged-rgemma-window": lambda: _paged_case(
         "recurrentgemma-9b", 1, window=2048),
@@ -100,3 +103,15 @@ CASES = {
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = CASES[case]()
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("case", ["paged-qwen-T1", "paged-qwen-T4"])
+def test_paged_kernel_reads_the_pool_in_place(one_chip, case):
+    """The kernel's row view of a (P, page, KV, D) pool is a bitcast of
+    the layout XLA gives the pool: no copy of a whole pool per call."""
+    fn, shapes = CASES[case]()
+    pool = "bf16[%d," % shapes[1][0][0]
+    made = [line for line in _compiled_text(fn, one_chip, *shapes)
+            .splitlines() if "=" in line and pool in line.split("=", 1)[1]
+            .split("(", 1)[0] and "parameter(" not in line]
+    assert not [m for m in made if "bitcast(" not in m], made
