@@ -1,8 +1,10 @@
 """Whether what the timed path served is correct: the served tokens of a
 sample of requests against the plain float32 reference.
 
-Each request is run once through ``reference.dense_gqa`` over its prompt
-and served tokens (teacher-forced); at the position that produced each
+Each request is run once through the reference module that the
+configuration names (``ref``: its ``Spec``, ``logits_at`` and
+``Q_CHUNK``, ``bench/reference/__init__.py``) over its prompt and
+served tokens (teacher-forced); at the position that produced each
 served token the reference's logits say how far that token lies below
 the reference's best. The number compared, with its limit from the
 configuration file, is
@@ -22,8 +24,6 @@ from typing import Dict, List
 
 import jax.numpy as jnp
 import numpy as np
-
-from bench.reference import dense_gqa
 
 # the lower precisions the control runs the reference in
 CONTROLS = ("int8", "fp8")
@@ -56,38 +56,40 @@ def _teacher(prompt, served, s_pad: int, n_pad: int):
     return jnp.asarray(tok), jnp.asarray(pos)
 
 
-def _pad_len(n: int) -> int:
-    q = dense_gqa.Q_CHUNK
+def _pad_len(ref, n: int) -> int:
+    q = ref.Q_CHUNK
     return n if n <= q else -(-n // q) * q
 
 
-def reference_logits(spec, w, prompt, served, *, s_pad: int, n_pad: int,
-                     mode: str = "f32") -> np.ndarray:
+def reference_logits(ref, spec, w, prompt, served, *, s_pad: int,
+                     n_pad: int, mode: str = "f32") -> np.ndarray:
     tok, pos = _teacher(prompt, served, s_pad, n_pad)
-    out = dense_gqa.logits_at(spec, w, tok, pos, mode=mode)
+    out = ref.logits_at(spec, w, tok, pos, mode=mode)
     return np.asarray(out)[:len(served)]
 
 
-def compare(spec, w, requests, *, max_len: int, max_out: int,
+def compare(ref, spec, w, requests, *, max_len: int, max_out: int,
             control: bool = False) -> Dict[str, float]:
     """Gaps of the served tokens of ``requests`` ((prompt, served)
-    pairs); with ``control`` also the gaps of the tokens that the
-    reference in each of CONTROLS puts first at the same positions."""
-    s_pad, n_pad = _pad_len(max_len), max_out
+    pairs) under the reference module ``ref``; with ``control`` also the
+    gaps of the tokens that the reference in each of CONTROLS puts first
+    at the same positions."""
+    s_pad, n_pad = _pad_len(ref, max_len), max_out
     gaps, miss, c_gaps, c_miss = [], [], {}, {}
     for prompt, served in requests:
-        ref = reference_logits(spec, w, prompt, served, s_pad=s_pad,
-                               n_pad=n_pad)
-        top = ref.max(-1)
+        best = reference_logits(ref, spec, w, prompt, served, s_pad=s_pad,
+                                n_pad=n_pad)
+        top = best.max(-1)
         tok = np.asarray(served)
-        gaps.append(top - ref[np.arange(len(tok)), tok])
-        miss.append(tok != ref.argmax(-1))
+        gaps.append(top - best[np.arange(len(tok)), tok])
+        miss.append(tok != best.argmax(-1))
         for mode in CONTROLS if control else ():
-            low = reference_logits(spec, w, prompt, served, s_pad=s_pad,
-                                   n_pad=n_pad, mode=mode).argmax(-1)
+            low = reference_logits(ref, spec, w, prompt, served,
+                                   s_pad=s_pad, n_pad=n_pad,
+                                   mode=mode).argmax(-1)
             c_gaps.setdefault(mode, []).append(
-                top - ref[np.arange(len(low)), low])
-            c_miss.setdefault(mode, []).append(low != ref.argmax(-1))
+                top - best[np.arange(len(low)), low])
+            c_miss.setdefault(mode, []).append(low != best.argmax(-1))
     out = {"tokens": float(sum(len(g) for g in gaps)),
            "max_gap": float(np.concatenate(gaps).max()),
            "mismatch_share": float(np.concatenate(miss).mean())}

@@ -4,8 +4,9 @@ least time the chip needs for the work the algorithm needs (every live
 request's keys and values read once, its query read and output written,
 q k^T and p v), at the chip's HBM bandwidth or peak rate, whichever
 bounds, over the summed device time of the kernel's runs. The kernel runs as the
-op of its jitted wrapper, ``_paged`` (one per layer per step)."""
-from bench.counts import paged_attn_bytes, paged_attn_flops
+op of its jitted wrapper, ``_paged`` (one per layer per step). The bytes and
+operations are counted by the configuration's reference module
+(``run.counts``)."""
 from bench.readers import traced_steps
 
 KERNEL = "_paged"
@@ -20,6 +21,6 @@ def read(run):
     if not t or not steps:
         return None
     ctx = [c for _, _, cs in steps for c in cs]
-    need = max(paged_attn_bytes(run.shapes, ctx) / run.peaks["hbm_bytes_per_s"],
-               paged_attn_flops(run.shapes, ctx) / run.peaks["bf16_flops_per_s"])
+    need = max(run.counts.paged_attn_bytes(ctx) / run.peaks["hbm_bytes_per_s"],
+               run.counts.paged_attn_flops(ctx) / run.peaks["bf16_flops_per_s"])
     return 100.0 * need / t

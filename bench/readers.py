@@ -10,8 +10,6 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Tuple
 
-from bench.counts import Shapes
-
 
 @dataclasses.dataclass
 class Run:
@@ -22,7 +20,7 @@ class Run:
     end: float                       # window closes
     drained: float                   # last tick after the window
     log: object                      # loop.Log
-    shapes: Shapes
+    counts: object                   # the reference module's counts(model)
     peaks: dict                      # bench/peaks.json entry of the chip
     trace: Optional[object] = None   # xplane.Trace of the traced run
     traced: Optional[Tuple[float, float]] = None   # traced window (clock)

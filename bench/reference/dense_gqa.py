@@ -23,17 +23,24 @@ inputs of every matrix product rounded to int8, or to fp8 (e4m3), each
 scaled by its absolute maximum (activations per row, weights per output
 channel, attention operands per row): the precision one step below the
 bfloat16 the models are served in.
+
+``plain`` gives the program's parameter tree under this module's names;
+``counts`` gives the operations and bytes the dense stack needs, from
+the configuration's shapes alone (the same count whatever implements
+the work): multiply-adds as 2 operations, weights and KV in bfloat16.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Iterable
 
 import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 Q_CHUNK = 512
+BF16 = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +68,80 @@ class Spec:
                    rope_theta=float(m["rope_theta"]),
                    eps=float(m["rms_norm_eps"]),
                    tied=bool(m["tie_word_embeddings"]))
+
+    @property
+    def layer_matmul_params(self) -> int:
+        """Weights one token multiplies per layer: q, k, v, o and the
+        three SwiGLU matrices."""
+        hd = self.head_dim
+        return (self.d * (self.heads + 2 * self.kv_heads) * hd
+                + self.heads * hd * self.d + 3 * self.d * self.d_ff)
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Keys and values of one token over every layer."""
+        return 2 * self.layers * self.kv_heads * self.head_dim * BF16
+
+    def token_flops(self, context: int, logits: bool) -> float:
+        """One token through the stack, attending to ``context`` keys
+        (itself included); ``logits`` adds the output head."""
+        f = 2 * self.layers * self.layer_matmul_params
+        # q k^T and p v
+        f += 4 * self.layers * self.heads * self.head_dim * context
+        if logits:
+            f += 2 * self.d * self.vocab
+        return float(f)
+
+    def prefill_flops(self, n: int) -> float:
+        """A prompt of ``n`` tokens, causal, logits at the last one only."""
+        f = 2 * self.layers * self.layer_matmul_params * n
+        f += 4 * self.layers * self.heads * self.head_dim * n * (n + 1) / 2
+        return float(f + 2 * self.d * self.vocab)
+
+    def decode_flops(self, contexts: Iterable[int]) -> float:
+        """One decode step: one token per live request, each at its
+        context."""
+        return sum(self.token_flops(c, True) for c in contexts)
+
+    def paged_attn_bytes(self, contexts: Iterable[int]) -> float:
+        """Decode attention over every layer for one step: each live
+        request's keys and values read once, its query read and output
+        written (bfloat16)."""
+        qo = 2 * self.heads * self.head_dim * BF16
+        return float(sum(c * self.kv_bytes_per_token + self.layers * qo
+                         for c in contexts))
+
+    def paged_attn_flops(self, contexts: Iterable[int]) -> float:
+        return float(sum(4 * self.layers * self.heads * self.head_dim * c
+                         for c in contexts))
+
+
+def counts(model: dict) -> Spec:
+    """The counts of a configuration file's ``model`` object: its
+    ``Spec``, whose methods count."""
+    return Spec.from_model(model)
+
+
+def plain(tree) -> dict:
+    """The program's parameter tree under this module's flat names
+    (layer-stacked, layer axis first). Raises if the program's layout is
+    not the dense GQA stack this module computes."""
+    blk = tree["blocks"]
+    if blk["tail"] or set(blk["scan"]) != {"0"}:
+        raise ValueError("the reference computes one homogeneous stack")
+    b = blk["scan"]["0"]
+    a, m = b["attn"], b["mlp"]
+    w = {"ln1": b["ln1"]["scale"], "ln2": b["ln2"]["scale"],
+         "wq": a["wq"], "wk": a["wk"], "wv": a["wv"], "wo": a["wo"],
+         "wi": m["wi"], "wg": m["wg"], "wd": m["wo"]}
+    for k in ("bq", "bk", "bv"):
+        if k in a:
+            w[k] = a[k]
+    out = {"embed": tree["embed"], "final_norm": tree["final_norm"]["scale"],
+           "layers": w}
+    if "head" in tree:
+        out["head"] = tree["head"]
+    return out
 
 
 def _q8(x, axis, mode):

@@ -5,14 +5,18 @@
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``: the published model, the program's
-preset and the deployment) and a traffic mix (``bench/traffic/<mix>.json``,
-read by ``bench/traffic.py``). The deployment's ``flags`` are
-``launch/serve.add_server_flags`` flags by name: ``{"num_pages": 4096,
-"prefix_cache": true}`` is ``--num-pages=4096 --prefix-cache``. One run,
-one process:
+preset, the deployment and the reference module that checks and counts
+the architecture, ``bench/reference/__init__.py``) and a traffic mix
+(``bench/traffic/<mix>.json``, read by ``bench/traffic.py``). The
+deployment's ``flags`` are ``launch/serve.add_server_flags`` flags by
+name: ``{"num_pages": 4096, "prefix_cache": true}`` is
+``--num-pages=4096 --prefix-cache``. One run, one process:
 
-  1. the persistent compile cache at ``<checkout>/.jax_cache``;
-  2. weights drawn from ``--seed`` on the device (``bench/weights.py``);
+  1. the persistent compile cache at ``<checkout>/.jax_cache``; the
+     configuration's reference module, loaded from its ``reference``
+     path;
+  2. weights drawn from ``--seed`` on the device (``bench/weights.py``,
+     with the reference's rules for leaves of its own);
   3. the engine, or the router over replicas, as
      ``repro.launch.serve.build_server`` builds it from those flags;
   4. warm-up: on every engine, one request per prefill bucket the
@@ -53,13 +57,14 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import jax  # noqa: E402
 
-from bench import check, counts, loop, traffic, weights, xplane  # noqa: E402
+from bench import check, loop, traffic, weights, xplane  # noqa: E402
 from bench.readers import Run  # noqa: E402
-from bench.reference import dense_gqa  # noqa: E402
 
 BENCH_DIR = ROOT / "bench"
 WARM_NEW = 3                       # tokens per warm-up request
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# what a reference module provides (bench/reference/__init__.py)
+REFERENCE_API = ("Spec", "logits_at", "Q_CHUNK", "plain", "counts")
 
 
 def log(msg: str) -> None:
@@ -86,14 +91,47 @@ def metrics_for(spec: dict, cell: dict, trace: bool) -> list:
             if cell["name"] in m.get("workloads", [cell["name"]])]
 
 
+def load_module(path: Path, name: str):
+    """The Python file ``path`` as the module ``name``, executed once per
+    process and registered in ``sys.modules`` (a dataclass resolves its
+    annotations there)."""
+    if name not in sys.modules:
+        sp = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(sp)
+        sys.modules[name] = mod
+        try:
+            sp.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def read_metric(name: str, run: Run):
     path = BENCH_DIR / "metrics" / f"{name}.py"
     if not path.exists():            # e.g. prefill_ms_per_ktok.longdoc
         path = BENCH_DIR / "metrics" / f"{name.split('.')[0]}.py"
-    sp = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read(run)
+    return load_module(path, f"metric_{path.stem}").read(run)
+
+
+def load_reference(conf: dict):
+    """The reference module that the configuration's ``reference`` key
+    names (a path from the checkout's root): it checks the served tokens
+    and counts the work. Exits with a message where the key, the file or
+    one of ``REFERENCE_API`` is missing."""
+    rel = conf.get("reference")
+    if not rel:
+        raise SystemExit("the configuration names no reference module "
+                         "(key 'reference')")
+    path = ROOT / rel
+    if not path.is_file():
+        raise SystemExit(f"no reference module at {rel!r}")
+    mod = load_module(path, f"reference:{rel}")
+    missing = [a for a in REFERENCE_API if not hasattr(mod, a)]
+    if missing:
+        raise SystemExit(f"reference module {rel!r} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def chips(n: int) -> dict:
@@ -226,10 +264,11 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, cfg, seed: int,
     program's config; ``rate`` overrides the mix's (the knee sweep);
     ``engine_hook`` (tests) may break the engine before the window;
     ``control`` also reads the lower-precision references."""
+    ref = load_reference(conf)
     flags = conf["deployment"]["flags"]
     model = conf["model"]
     t = loop.clock()
-    params = weights.make(cfg, seed)
+    params = weights.make(cfg, seed, getattr(ref, "WEIGHT_RULES", None))
     log(f"weights: {cfg.name} made in {loop.clock() - t:.2f} s")
     server = build_server(cfg, params, flags)
     engines = getattr(server, "engines", [server])
@@ -284,7 +323,7 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, cfg, seed: int,
             f"{sum(late) / len(late)!r} s")
 
     run = Run(setup_s=setup_s, t0=t0, end=t0 + seconds, drained=drained,
-              log=drv.log, shapes=counts.Shapes.from_model(model),
+              log=drv.log, counts=ref.counts(model),
               peaks=peaks or {})
     if tracer is not None:
         if keep_trace:
@@ -296,12 +335,12 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, cfg, seed: int,
     del drv, server, engines, first
     gc.collect()
 
-    spec = dense_gqa.Spec.from_model(model)
-    w = weights.plain(params)
+    spec = ref.Spec.from_model(model)
+    w = ref.plain(params)
     sample = check.sample(recs, seed)
     t = loop.clock()
     numbers = check.compare(
-        spec, w, [(r.req.prompt, r.req.generated) for r in sample],
+        ref, spec, w, [(r.req.prompt, r.req.generated) for r in sample],
         max_len=max_len, max_out=mix["output_tokens"]["max"],
         control=control)
     ok, rows = check.verdict(numbers, conf["check"])

@@ -7,7 +7,11 @@ benchmark's own, so the plain reference and the program are handed the
 same arrays and neither depends on how the other makes them. Biases and
 norm scales are drawn away from zero and one, so the check sees them.
 
-``plain`` gives the same arrays under the reference's names.
+The rules here cover the leaves of a dense GQA tree: norm scales, q/k/v
+biases, the embedding and the matrices in ``MATRICES``. A leaf of any
+other name is drawn by the rule the configuration's reference module
+supplies for it (``WEIGHT_RULES``, ``bench/reference/__init__.py``);
+one that no rule covers stops ``make`` with its path.
 """
 from __future__ import annotations
 
@@ -23,6 +27,8 @@ from repro.models import api
 EMBED_STD = 0.02
 BIAS_STD = 0.5
 SCALE_STD = 0.1
+# matrix leaves drawn at fan_in ** -0.5
+MATRICES = ("wq", "wk", "wv", "wo", "wi", "wg", "head")
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -41,8 +47,14 @@ def _fan_in(names, shape) -> int:
     return shape[-2]
 
 
-def _leaf(key, names, sd):
+def _leaf(key, names, sd, rules):
     leaf = names[-1]
+    if leaf in rules:
+        return rules[leaf](key, tuple(names), sd.shape).astype(sd.dtype)
+    if leaf not in ("scale", "bq", "bk", "bv", "embed") + MATRICES:
+        raise ValueError(f"no weight rule for leaf {'/'.join(names)} "
+                         f"{sd.shape}: the configuration's reference module "
+                         f"supplies none (WEIGHT_RULES)")
     z = jax.random.normal(key, sd.shape, jnp.float32)
     if leaf == "scale":
         x = 1.0 + SCALE_STD * z
@@ -59,38 +71,21 @@ def _names(path):
     return [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _make(cfg, key):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _make(cfg, rules, key):
     shapes = api.param_shapes(cfg)
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
-    leaves = [_leaf(jax.random.fold_in(key, i), _names(p), sd)
+    by_name = dict(rules)
+    leaves = [_leaf(jax.random.fold_in(key, i), _names(p), sd, by_name)
               for i, (p, sd) in enumerate(flat)]
     return jax.tree_util.tree_unflatten(tree, leaves)
 
 
-def make(cfg, seed: int):
-    """The program's parameter tree for ``cfg``, drawn from ``seed``."""
+def make(cfg, seed: int, rules=None):
+    """The program's parameter tree for ``cfg``, drawn from ``seed``.
+    ``rules`` maps a leaf name to ``rule(key, path, shape)``, which
+    returns that leaf's float32 values; it is consulted before the rules
+    here."""
+    rules = tuple(sorted((rules or {}).items()))
     # repro-lint: disable=host-sync — set-up waits for the weights once
-    return jax.block_until_ready(_make(cfg, seed_key(seed)))
-
-
-def plain(tree) -> dict:
-    """The same arrays under the reference's flat names (layer-stacked,
-    layer axis first). Raises if the program's layout is not the dense
-    GQA stack the reference computes."""
-    blk = tree["blocks"]
-    if blk["tail"] or set(blk["scan"]) != {"0"}:
-        raise ValueError("the reference computes one homogeneous stack")
-    b = blk["scan"]["0"]
-    a, m = b["attn"], b["mlp"]
-    w = {"ln1": b["ln1"]["scale"], "ln2": b["ln2"]["scale"],
-         "wq": a["wq"], "wk": a["wk"], "wv": a["wv"], "wo": a["wo"],
-         "wi": m["wi"], "wg": m["wg"], "wd": m["wo"]}
-    for k in ("bq", "bk", "bv"):
-        if k in a:
-            w[k] = a[k]
-    out = {"embed": tree["embed"], "final_norm": tree["final_norm"]["scale"],
-           "layers": w}
-    if "head" in tree:
-        out["head"] = tree["head"]
-    return out
+    return jax.block_until_ready(_make(cfg, rules, seed_key(seed)))

@@ -1,6 +1,8 @@
 """A whole benchmark run at smoke size on the CPU, past the chip check:
 weights, engine, warm-up, window and the reference check, as
-``bench/run.py`` runs a cell (Pallas in interpret mode)."""
+``bench/run.py`` runs a cell (Pallas in interpret mode). The
+configuration names its reference as the cells' files do: a module that
+computes as ``dense_gqa`` and records the harness's calls into it."""
 import dataclasses
 import sys
 from pathlib import Path
@@ -26,6 +28,7 @@ CONF = {
     # 0.0060 over five seeds and the reference in fp8 at least 0.019 over
     # four: the limit sits between, as the cells' limits do at full size
     "check": {"max_gap": 0.012},
+    "reference": "tests/bench/data/recording_reference.py",
 }
 MIX = {"loop": "open", "rate_per_s": 8.0,
        "prompt_tokens": {"dist": "uniform", "min": 17, "max": 30},

@@ -46,7 +46,7 @@ def test_reference_matches_a_direct_forward():
     import jax
     from bench import weights
     from bench.reference import dense_gqa
-    w = weights.plain(weights.make(bench_smoke.CFG, 4))
+    w = dense_gqa.plain(weights.make(bench_smoke.CFG, 4))
     spec = dense_gqa.Spec.from_model(bench_smoke.CONF["model"])
     toks = np.arange(1, 25, dtype=np.int32) % 256
     full = np.asarray(dense_gqa.logits_at(spec, w, toks, np.arange(24)))

@@ -1,6 +1,9 @@
-"""Operation and byte counts (bench/counts.py) and the readers that turn
-them into the roofline share and MFU, against hand counts on a toy
-shape and a made-up trace."""
+"""Operation and byte counts (``counts`` of bench/reference/dense_gqa.py)
+and the readers that turn them into the roofline share and MFU, against
+hand counts on a toy shape and a made-up trace, and against the counts of
+both configurations as they were before the counts moved into the
+reference module."""
+import json
 import importlib.util
 import sys
 from pathlib import Path
@@ -10,12 +13,28 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import counts, xplane  # noqa: E402
+from bench import xplane  # noqa: E402
 from bench.readers import Run  # noqa: E402
+from bench.reference import dense_gqa  # noqa: E402
 
-S = counts.Shapes(layers=2, d=8, heads=2, kv_heads=1, head_dim=4, d_ff=16,
-                  vocab=10)
+S = dense_gqa.Spec(layers=2, d=8, heads=2, kv_heads=1, head_dim=4, d_ff=16,
+                   vocab=10, rope_theta=1e4, eps=1e-6, tied=True)
 PEAKS = {"bf16_flops_per_s": 1e4, "hbm_bytes_per_s": 100.0}
+# contexts, and for each configuration the counts its cells were read
+# with before the counts moved into the reference module: decode_flops,
+# prefill_flops of each context's length, paged_attn_bytes and
+# paged_attn_flops of all the contexts
+CONTEXTS = [1, 700, 2048, 4096]
+GOLDEN = {
+    "qwen2.5-3b": (26704248832.0,
+                   [6171688960.0, 3957323923456.0, 11983883075584.0,
+                    25204094402560.0],
+                   253513728.0, 2018672640.0),
+    "granite-3-2b": (22509895680.0,
+                     [5067059200.0, 3486372474880.0, 10652055777280.0,
+                      22678299750400.0],
+                     562053120.0, 2242969600.0),
+}
 
 
 def reader(name):
@@ -32,13 +51,25 @@ def test_hand_counts():
     # k and v, 2 layers, 1 head of 4, bf16
     assert S.kv_bytes_per_token == 32
     # 2 * 2 layers * 576 + 4 * 2 layers * 2 heads * 4 * 5 keys + head 2*8*10
-    assert counts.token_flops(S, 5, True) == 2304 + 320 + 160
-    assert counts.token_flops(S, 5, False) == 2304 + 320
+    assert S.token_flops(5, True) == 2304 + 320 + 160
+    assert S.token_flops(5, False) == 2304 + 320
     # 4 prompt tokens: matmuls, causal 1+2+3+4 keys, head at the last only
-    assert counts.prefill_flops(S, 4) == 9216 + 64 * 10 + 160
+    assert S.prefill_flops(4) == 9216 + 64 * 10 + 160
     # 8 live tokens of KV, plus q in and out per request per layer
-    assert counts.paged_attn_bytes(S, [5, 3]) == 8 * 32 + 2 * 2 * 32
-    assert counts.paged_attn_flops(S, [5, 3]) == 4 * 2 * 2 * 4 * 8
+    assert S.paged_attn_bytes([5, 3]) == 8 * 32 + 2 * 2 * 32
+    assert S.paged_attn_flops([5, 3]) == 4 * 2 * 2 * 4 * 8
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN))
+def test_configuration_counts_are_unchanged_by_the_move(name):
+    from bench import run
+    conf = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    c = run.load_reference(conf).counts(conf["model"])
+    decode, prefill, attn_bytes, attn_flops = GOLDEN[name]
+    assert c.decode_flops(CONTEXTS) == decode
+    assert [c.prefill_flops(n) for n in CONTEXTS] == prefill
+    assert c.paged_attn_bytes(CONTEXTS) == attn_bytes
+    assert c.paged_attn_flops(CONTEXTS) == attn_flops
 
 
 def _run(kernel_s=7.68, busy_s=10.0):
@@ -51,7 +82,7 @@ def _run(kernel_s=7.68, busy_s=10.0):
     log.steps = [(0.5, 1.0, [5, 3]), (5.0, 6.0, [9])]    # 2nd outside
     log.prefills = [(0.2, 0.4, 4)]
     log.recs = {}
-    return Run(setup_s=1.0, t0=0.0, end=3.0, drained=3.0, log=log, shapes=S,
+    return Run(setup_s=1.0, t0=0.0, end=3.0, drained=3.0, log=log, counts=S,
                peaks=PEAKS, trace=tr, traced=(0.0, 2.0))
 
 

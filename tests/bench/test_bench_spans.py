@@ -96,7 +96,7 @@ def make_run():
     for rid, due in ((7, 100.2), (8, 100.6), (9, 99.0)):
         log.recs[rid] = loop.Rec(rid, 10, 5, due, added=due)
     return Run(setup_s=1.0, t0=100.0, end=101.0, drained=101.0, log=log,
-               shapes=None, peaks={}, trace=trace, traced=(100.0, 101.0))
+               counts=None, peaks={}, trace=trace, traced=(100.0, 101.0))
 
 
 @pytest.fixture

@@ -123,7 +123,7 @@ def _run(eng, items, seconds, lead=0.0):
     t0 = loop.clock() + lead
     drv.run_open(items, t0, seconds)
     return Run(setup_s=0.0, t0=t0, end=t0 + seconds, drained=loop.clock(),
-               log=drv.log, shapes=None, peaks={})
+               log=drv.log, counts=None, peaks={})
 
 
 def test_open_loop_stamps_every_token_and_admission():
