@@ -21,6 +21,7 @@ ARCHS: List[str] = [
     "internvl2-2b",
     "mamba2-2.7b",
     "recurrentgemma-9b",
+    "granite-4.0-h-small",
 ]
 
 

@@ -21,9 +21,13 @@ import jax.numpy as jnp
 class MoEConfig:
     num_experts: int = 0
     top_k: int = 1
+    # > 0: the token-dropping layer (layers.moe_apply), capacity per
+    # group; 0: the dropless routed layer (layers.routed_apply) over the
+    # experts this chip holds (ModelConfig.experts_first/_held)
     capacity_factor: float = 1.25
     moe_every: int = 1          # MoE FFN every Nth layer (1 = every layer)
     shared_expert: bool = False  # extra always-on expert (llama4 style)
+    shared_d_ff: int = 0         # the shared expert's width (0 = d_ff)
     # GShard-style dispatch groups: capacity is enforced per group and the
     # group dim is sharded with the batch, so routing scatters stay local
     # to their data shard (EXPERIMENTS.md §Perf B6). 32 = pod*data of the
@@ -99,12 +103,37 @@ class ModelConfig:
     # per-model static absmax bound (EXPERIMENTS.md §Perf C4).
     kv_cache_dtype: str = "bfloat16"
     kv_scale: float = 8.0
+    # Granite's scalings; the defaults leave a model without them as it
+    # was. The embedding is multiplied by embedding_multiplier, every
+    # mixer and FFN branch by residual_multiplier before its residual
+    # add, and the logits divided by logits_scaling; attention_multiplier
+    # is the softmax scale (0 = 1/sqrt(head_dim)); norm_eps is every
+    # RMSNorm's epsilon.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
+    # "rope" (rotary q and k) | "nope" (no positional embedding)
+    position_embedding: str = "rope"
+    # the routed experts this chip holds of each dropless routed layer:
+    # experts_first .. experts_first + experts_held - 1 (0 held = all).
+    # The router still scores every expert; rows routed elsewhere add
+    # nothing here (one chip's share of an expert-parallel deployment).
+    experts_first: int = 0
+    experts_held: int = 0
     # notes (arch-applicability etc.)
     note: str = ""
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return (self.experts_first,
+                self.experts_held or self.moe.num_experts - self.experts_first)
 
     @property
     def is_encdec(self) -> bool:
@@ -119,39 +148,75 @@ class ModelConfig:
         """True when the arch supports ~500k-token decode (no full-attn cache)."""
         return self.family in ("ssm", "hybrid")
 
-    def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks), used for 6ND."""
-        d, dff, v = self.d_model, self.d_ff, self.vocab
-        hd = self.resolved_head_dim
-        attn = d * self.num_heads * hd + 2 * d * self.kv_heads * hd + self.num_heads * hd * d
+    def _attn_params(self) -> int:
+        d, hd = self.d_model, self.resolved_head_dim
+        attn = (d * self.num_heads * hd + 2 * d * self.kv_heads * hd
+                + self.num_heads * hd * d)
         if self.qkv_bias:
             attn += (self.num_heads + 2 * self.kv_heads) * hd
-        ffn_dense = (3 if self.gated_ffn else 2) * d * dff
+        return attn
+
+    def _ffn_params(self, d_ff: int) -> int:
+        return (3 if self.gated_ffn else 2) * self.d_model * d_ff
+
+    def _ssm_params(self) -> int:
+        """One Mamba-2 mixer: in/out projections, conv, A."""
+        s, d = self.ssm, self.d_model
+        d_inner = s.expand * d
+        return (d * (2 * d_inner + 2 * s.num_groups * s.state_dim
+                     + d_inner // s.head_dim)
+                + d_inner * s.conv_width + d_inner * d
+                + d_inner // s.head_dim)
+
+    def _moe_params(self, active: bool) -> int:
+        """One routed FFN: the experts (those a token runs when
+        ``active``), the router and the shared expert."""
+        moe = self.moe
+        n = moe.top_k if active else moe.num_experts
+        ffn = n * self._ffn_params(self.d_ff)
+        if not active:
+            ffn += self.d_model * moe.num_experts
+        if moe.shared_expert:
+            ffn += self._ffn_params(moe.shared_d_ff or self.d_ff)
+        return ffn
+
+    def _hybrid_params(self, active: bool) -> int:
+        """Blocks of a hybrid pattern, layer by layer: the mixer of each
+        kind, then its FFN (none after a bare ``ssm`` mixer)."""
+        h = self.hybrid
+        lru = h.lru_dim or self.d_model
+        # RG-LRU block: in/out proj + gates (approx)
+        lru_block = 2 * self.d_model * lru + 3 * lru * lru
+        kinds = (h.pattern * self.num_layers)[: self.num_layers]
+        total = 0
+        for kind in kinds:
+            if kind in ("ssm", "ssm_moe"):
+                total += self._ssm_params()
+            elif kind == "rglru":
+                total += lru_block
+            else:
+                total += self._attn_params()
+            if kind.endswith("_moe"):
+                total += self._moe_params(active)
+            elif kind != "ssm":
+                total += self._ffn_params(self.d_ff)
+        return total
+
+    def param_count(self) -> int:
+        """Analytic parameter count (embedding + blocks), used for 6ND."""
+        attn = self._attn_params()
+        ffn_dense = self._ffn_params(self.d_ff)
         total = 0
         if self.family == "ssm":
-            s = self.ssm
-            d_inner = s.expand * d
-            per_layer = (
-                d * (2 * d_inner + 2 * s.num_groups * s.state_dim + d_inner // s.head_dim)
-                + d_inner * s.conv_width
-                + d_inner * d
-                + d_inner // s.head_dim  # A
-            )
-            total = self.num_layers * per_layer
+            total = self.num_layers * self._ssm_params()
         elif self.family == "hybrid":
-            h = self.hybrid
-            lru = h.lru_dim or self.d_model
-            n_attn = sum(1 for b in (h.pattern * self.num_layers)[: self.num_layers] if b == "local_attn")
-            n_lru = self.num_layers - n_attn
-            # RG-LRU block: in/out proj + gates
-            lru_block = 2 * d * lru + 3 * lru * lru // 1  # approx (x,gate projections + recurrent gates)
-            total = n_attn * (attn + ffn_dense) + n_lru * (lru_block + ffn_dense)
+            total = self._hybrid_params(active=False)
         else:
             moe = self.moe
             for layer in range(self.num_layers):
                 is_moe = moe is not None and moe.num_experts > 0 and (layer % moe.moe_every == moe.moe_every - 1)
                 if is_moe:
-                    ffn = moe.num_experts * ffn_dense + d * moe.num_experts
+                    ffn = moe.num_experts * ffn_dense + self.d_model * moe.num_experts
                     if moe.shared_expert:
                         ffn += ffn_dense
                 else:
@@ -162,17 +227,18 @@ class ModelConfig:
                 # add cross-attention to each decoder layer
                 total += self.enc_layers * (attn + ffn_dense)
                 total += self.num_layers * attn  # cross-attn
-        total += v * d * (1 if self.tie_embeddings else 2)
+        total += self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
         return int(total)
 
     def active_param_count(self) -> int:
         """Per-token active params (= total for dense; routed subset for MoE)."""
         if self.moe is None or self.moe.num_experts == 0:
             return self.param_count()
-        d, dff = self.d_model, self.d_ff
-        hd = self.resolved_head_dim
-        attn = d * self.num_heads * hd + 2 * d * self.kv_heads * hd + self.num_heads * hd * d
-        ffn_dense = (3 if self.gated_ffn else 2) * d * dff
+        embed = self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
+        if self.family == "hybrid":
+            return int(self._hybrid_params(active=True) + embed)
+        attn = self._attn_params()
+        ffn_dense = self._ffn_params(self.d_ff)
         moe = self.moe
         total = 0
         for layer in range(self.num_layers):
@@ -182,8 +248,7 @@ class ModelConfig:
             else:
                 ffn = ffn_dense
             total += attn + ffn
-        total += self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
-        return int(total)
+        return int(total + embed)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import jax
 
 from repro.kernels import attention as _attention
 from repro.kernels import conv_im2col as _conv
+from repro.kernels import experts as _experts
 from repro.kernels import gemm_os as _gemm
 from repro.kernels import paged_attention as _paged
 from repro.kernels import ref as _ref
@@ -63,7 +64,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_table: jax.Array, lengths, *,
                     kv_scale: Optional[float] = None,
-                    window: int = 0) -> jax.Array:
+                    window: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """Flash-decode over a paged KV pool: the block-table indirection runs
     INSIDE the kernel (scalar-prefetched table, double-buffered multi-page
     KV blocks, online softmax), so the per-layer dense gather of the PR-1 serving
@@ -75,10 +77,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     (base + T; T == 1 reduces to the old pos + 1 contract). window > 0 =
     sliding-window attention (hybrid local_attn layers): rows see at most
     the last `window` keys, and pages entirely below the window — which
-    the serving engine recycles to scratch — are skipped in-grid."""
+    the serving engine recycles to scratch — are skipped in-grid. scale:
+    the softmax scale (None = 1/sqrt(D))."""
     return _paged.paged_attention(q, k_pool, v_pool, block_table, lengths,
                                   kv_scale=kv_scale, window=window,
-                                  interpret=_interpret())
+                                  scale=scale, interpret=_interpret())
+
+
+def swiglu_experts(x: jax.Array, wg: jax.Array, wi: jax.Array,
+                   wo: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """Rows sorted by held expert through their expert's SwiGLU, one
+    grouped Pallas call per product (kernels/experts.py); zero past the
+    ``group_sizes.sum()`` routed rows."""
+    return _experts.swiglu_experts(x, wg, wi, wo, group_sizes,
+                                   _interpret())
 
 
 def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1) -> jax.Array:

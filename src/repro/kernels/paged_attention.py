@@ -274,6 +274,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_table: jax.Array, lengths, *,
                     kv_scale: Optional[float] = None, window: int = 0,
+                    scale: Optional[float] = None,
                     interpret: bool = True) -> jax.Array:
     """Flash-decode over a paged KV pool. Returns q's shape.
 
@@ -297,6 +298,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                  serving engine recycles them (their table entries are
                  scratch), so a windowed layer's sweep AND footprint stay
                  O(window) however long the request runs.
+    scale:       static softmax scale (None = 1/sqrt(D)).
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -308,15 +310,16 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                           min(block_table.shape[1], k_pool.shape[0]), window)
     out = _paged(q, k_pool, v_pool, block_table, lengths,
                  kv_scale=kv_scale, window=window, ppb=ppb,
-                 interpret=interpret)
+                 scale=scale, interpret=interpret)
     return out[:, 0] if squeeze else out
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("kv_scale", "window", "ppb", "interpret"))
+                   static_argnames=("kv_scale", "window", "ppb", "scale",
+                                    "interpret"))
 def _paged(q, k_pool, v_pool, block_table, lengths, *,
-           kv_scale: Optional[float], window: int, ppb: int, interpret: bool
-           ) -> jax.Array:
+           kv_scale: Optional[float], window: int, ppb: int,
+           interpret: bool, scale: Optional[float] = None) -> jax.Array:
     B, T, H, D = q.shape
     P, page, KV, _ = k_pool.shape
     assert H % KV == 0, (H, KV)
@@ -368,7 +371,8 @@ def _paged(q, k_pool, v_pool, block_table, lengths, *,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page=page, ppb=ppb,
-                          n_blocks=n_blocks, T=T, group=G, scale=D ** -0.5,
+                          n_blocks=n_blocks, T=T, group=G,
+                          scale=D ** -0.5 if scale is None else scale,
                           dequant=dequant, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, R, W), q.dtype),

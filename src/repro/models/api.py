@@ -72,18 +72,32 @@ def param_shapes(cfg):
 
 
 def _embed_tokens(cfg, params, tokens):
-    return jnp.take(params["embed"], tokens, axis=0)
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _scale_logits(cfg, logits):
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _logits(cfg, params, x):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32)
+    return _scale_logits(
+        cfg, jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32))
+
+
+def _final_norm(cfg, p, x):
+    return norm_apply(p, x, cfg.norm, cfg.norm_eps)
 
 
 def _encode(cfg, params, enc_embeds, rules):
     x, _, _ = tfm.stack_apply(cfg, params["enc"]["blocks"], enc_embeds,
                               ("enc",), (), rules=rules)
-    return norm_apply(params["enc"]["final_norm"], x, cfg.norm)
+    return _final_norm(cfg, params["enc"]["final_norm"], x)
 
 
 def forward_hidden(cfg, params, batch: Dict[str, Any], *,
@@ -129,7 +143,7 @@ def forward_hidden(cfg, params, batch: Dict[str, Any], *,
                                      max_len=max_len, prefix_kv=prefix_kv,
                                      prefix_len=prefix_len, length=length,
                                      paged_kv=paged_kv)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
+    x = _final_norm(cfg, params["final_norm"], x)
     return x, caches, aux
 
 
@@ -150,7 +164,8 @@ CE_CHUNK = 512
 def _ce_chunk(cfg, params, x, labels, rules):
     """CE over one sequence chunk; logits (B, c, Vp) live only inside."""
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32)
+    logits = _scale_logits(
+        cfg, jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32))
     logits = rules.cons(logits, "batch,seq,vocab")
     vp = logits.shape[-1]
     if vp > cfg.vocab:
@@ -249,7 +264,7 @@ def prefill(cfg, params, batch, *, rules: Rules = NO_RULES, max_len=None,
 
 def decode_step(cfg, params, cache, tokens, pos, *,
                 rules: Rules = NO_RULES, block_table=None,
-                win_block_table=None):
+                win_block_table=None, expert_rows: bool = False):
     """tokens: (B, T) int32 — T == 1 for plain decode, T > 1 for a
     speculative multi-token verify block (paged caches only; token t of
     request b sits at absolute position pos[b] + t). pos: (B,) position of
@@ -267,7 +282,10 @@ def decode_step(cfg, params, cache, tokens, pos, *,
     single-token only, so a T > 1 block on a windowed stack WITHOUT the
     paged window layout is rejected here with a ValueError naming the
     layer kind (instead of the bare shape assert it used to die with
-    deep inside the jit trace)."""
+    deep inside the jit trace).
+    expert_rows=True adds a third result: the routed rows of each held
+    expert of every dropless routed layer, (layers, held) int32 (None
+    where there is none; transformer.stack_decode)."""
     kinds = tfm.pattern_for(cfg)
     _, tail = tfm.layer_plan(cfg)
     if tokens.shape[1] > 1:
@@ -288,16 +306,16 @@ def decode_step(cfg, params, cache, tokens, pos, *,
                 f"to)")
     x = _embed_tokens(cfg, params, tokens)
     x = rules.cons(x, "batch,seq,embed")
-    x, new_cache = tfm.stack_decode(cfg, params["blocks"], x, cache, pos,
-                                    kinds, tail, rules=rules,
-                                    block_table=block_table,
-                                    win_block_table=win_block_table)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
+    x, new_cache, *rows = tfm.stack_decode(
+        cfg, params["blocks"], x, cache, pos, kinds, tail, rules=rules,
+        block_table=block_table, win_block_table=win_block_table,
+        expert_rows=expert_rows)
+    x = _final_norm(cfg, params["final_norm"], x)
     if tokens.shape[1] == 1:
-        logits = _logits(cfg, params, x)[:, 0]
-        return rules.cons(logits, "batch,vocab"), new_cache
-    logits = _logits(cfg, params, x)
-    return rules.cons(logits, "batch,seq,vocab"), new_cache
+        logits = rules.cons(_logits(cfg, params, x)[:, 0], "batch,vocab")
+    else:
+        logits = rules.cons(_logits(cfg, params, x), "batch,seq,vocab")
+    return (logits, new_cache, *rows)
 
 
 # ---------------------------------------------------------------------------
@@ -321,7 +339,7 @@ def _block_cache_init(cfg, kind: str, batch: int, seq_len: int):
         w = cfg.hybrid.window  # ring buffer is always window-sized
         return {"k": jnp.zeros((batch, w, kv, hd), dt),
                 "v": jnp.zeros((batch, w, kv, hd), dt)}
-    if kind == "ssm":
+    if kind in ("ssm", "ssm_moe"):
         return ssm.ssm_cache_init(cfg, batch)
     if kind == "rglru":
         return griffin.rglru_cache_init(cfg, batch)
@@ -344,7 +362,7 @@ def cache_init(cfg, batch: int, seq_len: int):
 
 PAGEABLE_KINDS = ("attn_mlp", "attn_moe")       # full-attention page pools
 WINDOW_KINDS = ("local_attn",)                  # sliding-window page pools
-STATE_KINDS = ("ssm", "rglru")                  # fixed-size per-slot state
+STATE_KINDS = ("ssm", "ssm_moe", "rglru")       # fixed-size per-slot state
 # every block kind the PagedServingEngine can host (encoder-decoder stays
 # on the dense engine: cross-attention KV is per-request, not paged)
 PAGED_SERVABLE_KINDS = PAGEABLE_KINDS + WINDOW_KINDS + STATE_KINDS
@@ -379,7 +397,7 @@ def paged_cache_init(cfg, batch: int, num_pages: int, page_size: int):
         if kind in PAGEABLE_KINDS + WINDOW_KINDS:
             return {"k": jnp.zeros((num_pages, page_size, kv, hd), dt),
                     "v": jnp.zeros((num_pages, page_size, kv, hd), dt)}
-        if kind == "ssm":
+        if kind in ("ssm", "ssm_moe"):
             return ssm.ssm_cache_init(cfg, batch)
         return griffin.rglru_cache_init(cfg, batch)
 
@@ -439,6 +457,13 @@ def state_slot_import(cfg, cache, slot, state_tree):
                 if kd in state else e
                 for i, (e, kd) in enumerate(zip(cache["tail"], tail))]
     return {"scan": new_scan, "tail": new_tail}
+
+
+def state_slot_reset(cfg, cache, slot):
+    """Zero one slot's recurrent state (every ssm/ssm_moe/rglru layer);
+    pages and other slots pass through. ``slot`` may be traced."""
+    zeros = jax.tree.map(jnp.zeros_like, state_slot_export(cfg, cache, slot))
+    return state_slot_import(cfg, cache, slot, zeros)
 
 
 def paged_cache_axes(cfg):

@@ -1,5 +1,6 @@
 """Layer library: norms, rotary, GQA attention (full/windowed/cross/decode),
-chunked flash attention (pure-jnp online softmax), gated MLP and dropping MoE.
+chunked flash attention (pure-jnp online softmax), gated MLP, dropping MoE
+and the dropless routed layer over the experts a chip holds.
 
 All layers are functional: ``<layer>_init(mk, cfg, ...) -> params`` and
 ``<layer>_apply(cfg, params, ...) -> out``. ``mk`` is a ``Maker`` that either
@@ -14,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.experts import row_tile
 from repro.kernels.paged_attention import pool_rows
 from repro.parallel.sharding import NO_RULES, Rules
 
@@ -98,7 +100,7 @@ def rope(x, positions, theta: float):
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset=0, kv_valid: Optional[int] = None,
                     q_chunk: int = 256, kv_chunk: int = 512,
-                    chunked: bool = True):
+                    chunked: bool = True, scale: Optional[float] = None):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D). GQA via head grouping.
 
     window > 0 -> sliding-window causal attention.
@@ -109,11 +111,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     small — the chunk scan would otherwise materialize its (qc, kc)
     intermediates at every fusion boundary x trip count (the 36 TiB/step
     pathology of EXPERIMENTS.md §Perf A4).
+    scale: the softmax scale (None = 1/sqrt(D)).
     """
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     if not chunked:
         qg = q.reshape(B, Sq, KV, G, D)
         s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
@@ -191,7 +194,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def attend_decode(q, ck, cv, pos, *, window: int = 0, ring: bool = False,
-                  kv_chunk: int = 0):
+                  kv_chunk: int = 0, scale: Optional[float] = None):
     """Decode attention vs a cache. q: (B, Tq, H, D) — Tq == 1 for plain
     decode, Tq > 1 for a speculative multi-token query block; ck/cv:
     (B, S, KV, D); pos: (B,) absolute position of the FIRST new token
@@ -214,8 +217,10 @@ def attend_decode(q, ck, cv, pos, *, window: int = 0, ring: bool = False,
     Chunked over the cache length with an online softmax so the (B, KV, G,
     Tq, S) score tensor is never materialized — for a 32k cache this is
     the difference between streaming the cache once and ~6 fp32 passes
-    over a 17 GB intermediate (EXPERIMENTS.md §Perf C3)."""
+    over a 17 GB intermediate (EXPERIMENTS.md §Perf C3). ``scale`` is the
+    softmax scale (None = 1/sqrt(D))."""
     B, Tq, H, D = q.shape
+    scale = D ** -0.5 if scale is None else scale
     _, S, KV, _ = ck.shape
     G = H // KV
     qg = q.reshape(B, Tq, KV, G, D)
@@ -242,7 +247,7 @@ def attend_decode(q, ck, cv, pos, *, window: int = 0, ring: bool = False,
         kb = ckc[:, i]
         vb = cvc[:, i]
         s = jnp.einsum("btkgd,bckd->bkgtc", qg, kb,
-                       preferred_element_type=jnp.float32) * (D ** -0.5)
+                       preferred_element_type=jnp.float32) * scale
         slots = i * c + jnp.arange(c)
         mask = slots[None, None, :] < nvalid[:, :, None]       # (B, Tq, c)
         if window and not ring:
@@ -312,6 +317,20 @@ def attention_init(mk: Maker, cfg) -> Dict[str, Any]:
     return p
 
 
+def _scale(cfg) -> Optional[float]:
+    """The softmax scale: the configured attention multiplier, else None
+    (1/sqrt(head_dim))."""
+    return cfg.attention_multiplier or None
+
+
+def _position(cfg, q, k, positions):
+    """Rotary q and k at ``positions``; as they are under "nope"."""
+    if cfg.position_embedding == "nope":
+        return q, k
+    return rope(q, positions, cfg.rope_theta), rope(k, positions,
+                                                    cfg.rope_theta)
+
+
 def _qkv(cfg, p, x, kv_x=None):
     kv_x = x if kv_x is None else kv_x
     q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
@@ -345,14 +364,13 @@ def attention_apply(cfg, p, x, *, rules: Rules = NO_RULES, positions=None,
             q = q + p["bq"]
         k, v = cross_kv
         q = rules.cons(q, "batch,seq,heads")
-        out = flash_attention(q, k, v, causal=False)
+        out = flash_attention(q, k, v, causal=False, scale=_scale(cfg))
         kv = None
     else:
         q, k, v = _qkv(cfg, p, x)
         if positions is None:
             positions = jnp.arange(S)[None, :]
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k = _position(cfg, q, k, positions)
         q = rules.cons(q, "batch,seq,heads")
         k = rules.cons(k, "batch,seq,kv_heads")
         if prefix is not None:
@@ -367,10 +385,12 @@ def attention_apply(cfg, p, x, *, rules: Rules = NO_RULES, positions=None,
             vf = jax.lax.dynamic_update_slice(vf, v, start)
             out = flash_attention(q, kf, vf, causal=causal, window=window,
                                   q_offset=plen,
-                                  chunked=cfg.flash_chunking)
+                                  chunked=cfg.flash_chunking,
+                                  scale=_scale(cfg))
         else:
             out = flash_attention(q, k, v, causal=causal, window=window,
-                                  chunked=cfg.flash_chunking)
+                                  chunked=cfg.flash_chunking,
+                                  scale=_scale(cfg))
         kv = (k, v)
     out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
     # contraction over heads: under manual TP (ManualRules inside a
@@ -417,14 +437,13 @@ def attention_decode(cfg, p, x, cache, pos, *, rules: Rules = NO_RULES,
         ck = kv_dequant(cfg, cache["k"], x.dtype)
         cv = kv_dequant(cfg, cache["v"], x.dtype)
         n = jnp.full((x.shape[0],), ck.shape[1], jnp.int32)
-        out = attend_decode(q, ck, cv, n - 1)
+        out = attend_decode(q, ck, cv, n - 1, scale=_scale(cfg))
         new_cache = cache
     elif block_table is not None:
         B, T = x.shape[0], x.shape[1]
         q, k, v = _qkv(cfg, p, x)
         pos_t = pos[:, None] + jnp.arange(T)[None, :]        # (B, T)
-        q = rope(q, pos_t, cfg.rope_theta)
-        k = rope(k, pos_t, cfg.rope_theta)
+        q, k = _position(cfg, q, k, pos_t)
         page = cache["k"].shape[1]
         n_blk = block_table.shape[1]
         # physical destination of row t: page block_table[b, (pos+t)//page],
@@ -461,17 +480,17 @@ def attention_decode(cfg, p, x, cache, pos, *, rules: Rules = NO_RULES,
             out = attend_decode(q, kv_dequant(cfg, kg, q.dtype),
                                 kv_dequant(cfg, vg, q.dtype), pos,
                                 window=window,
-                                kv_chunk=cfg.decode_kv_chunk)
+                                kv_chunk=cfg.decode_kv_chunk,
+                                scale=_scale(cfg))
         else:
             scale = cfg.kv_scale if ck.dtype == jnp.int8 else None
             out = ops.paged_attention(q, ck, cv, block_table,
                                       pos + T, kv_scale=scale,
-                                      window=window)
+                                      window=window, scale=_scale(cfg))
         new_cache = {"k": ck, "v": cv}
     else:
         q, k, v = _qkv(cfg, p, x)
-        q = rope(q, pos[:, None], cfg.rope_theta)
-        k = rope(k, pos[:, None], cfg.rope_theta)
+        q, k = _position(cfg, q, k, pos[:, None])
         S = cache["k"].shape[1]
         slot = jnp.remainder(pos, S) if window else jnp.minimum(pos, S - 1)
         # one-hot masked write instead of a per-batch dynamic-update-slice:
@@ -487,7 +506,7 @@ def attention_decode(cfg, p, x, cache, pos, *, rules: Rules = NO_RULES,
         out = attend_decode(q, kv_dequant(cfg, ck, q.dtype),
                             kv_dequant(cfg, cv, q.dtype), pos,
                             window=window, ring=window > 0,
-                            kv_chunk=cfg.decode_kv_chunk)
+                            kv_chunk=cfg.decode_kv_chunk, scale=_scale(cfg))
         new_cache = {"k": ck, "v": cv}
     out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
     out = rules.contract(out, "heads")   # TP psum point (see attention_apply)
@@ -533,16 +552,24 @@ def mlp_apply(cfg, p, x, *, rules: Rules = NO_RULES):
 
 
 def moe_init(mk: Maker, cfg) -> Dict[str, Any]:
+    """The router scores every expert; the expert stacks hold every expert
+    for the dropping layer and the experts held here for the dropless one
+    (``routed_apply``)."""
     d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    held = cfg.held_experts[1] if is_dropless(cfg) else e
     p = {
         "router": mk((d, e), "wembed,wexperts", scale=d ** -0.5, dtype=jnp.float32),
-        "wi": mk((e, d, f), "wexperts,wembed,wff", scale=d ** -0.5),
-        "wg": mk((e, d, f), "wexperts,wembed,wff", scale=d ** -0.5),
-        "wo": mk((e, f, d), "wexperts,wff,wembed", scale=f ** -0.5),
+        "wi": mk((held, d, f), "wexperts,wembed,wff", scale=d ** -0.5),
+        "wg": mk((held, d, f), "wexperts,wembed,wff", scale=d ** -0.5),
+        "wo": mk((held, f, d), "wexperts,wff,wembed", scale=f ** -0.5),
     }
     if cfg.moe.shared_expert:
-        p["shared"] = mlp_init(mk, cfg)
+        p["shared"] = mlp_init(mk, cfg, cfg.moe.shared_d_ff or None)
     return p
+
+
+def is_dropless(cfg) -> bool:
+    return cfg.moe is not None and cfg.moe.capacity_factor <= 0
 
 
 def moe_apply(cfg, p, x, *, rules: Rules = NO_RULES):
@@ -609,3 +636,70 @@ def moe_apply(cfg, p, x, *, rules: Rules = NO_RULES):
     lb = E * jnp.sum(me * ce)
     z = jnp.mean(jax.scipy.special.logsumexp(logits, -1) ** 2)
     return rules.cons(out, "batch,seq,embed"), {"lb_loss": lb, "z_loss": z}
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed layer over the experts this chip holds
+# ---------------------------------------------------------------------------
+
+# tokens routed in one grouped call; a longer prefill runs its tokens
+# through the experts this many at a time, so the routed rows of one
+# call (tokens x top_k x d_model) stay a few hundred MB at most
+ROUTED_CHUNK = 2048
+
+
+def routed_apply(cfg, p, x, *, rules: Rules = NO_RULES):
+    """Dropless routed FFN. x: (B, S, d) -> (out, rows): ``rows`` (held,)
+    int32 counts the routed rows each held expert took.
+
+    The router scores all ``num_experts`` in float32; each token takes its
+    ``top_k`` best and weights them by the softmax over those logits (as
+    Granite's and Mixtral's routers do). Rows routed to experts this chip
+    holds (``cfg.held_experts``) run through their expert's SwiGLU in one
+    grouped call over the held experts, sorted by expert
+    (``kernels/experts.py``); rows routed to the other experts add
+    nothing here. No token is dropped, so a request's output does not
+    depend on the batch it shares. The shared expert, of its own width,
+    adds to every token."""
+    B, S, d = x.shape
+    first, held = cfg.held_experts
+    T = B * S
+    xt = x.reshape(T, d)
+    logits = xt.astype(jnp.float32) @ p["router"]             # (T, E)
+    top, idx = jax.lax.top_k(logits, cfg.moe.top_k)
+    gate = jax.nn.softmax(top, -1)
+    experts = (p["wg"], p["wi"], p["wo"])
+    # chunks in sequence, unrolled: a loop would carry the expert weights
+    # as loop state, a copy of them per layer
+    parts = [_routed_rows(xt[i:i + ROUTED_CHUNK], idx[i:i + ROUTED_CHUNK]
+                          - first, gate[i:i + ROUTED_CHUNK], experts, held)
+             for i in range(0, T, ROUTED_CHUNK)]
+    out = jnp.concatenate([o for o, _ in parts]).reshape(B, S, d)
+    rows = sum(r for _, r in parts)
+    if cfg.moe.shared_expert:
+        out = out + mlp_apply(cfg, p["shared"], x, rules=rules)
+    return rules.cons(out, "batch,seq,embed"), rows
+
+
+def _routed_rows(xt, local, gate, experts, held: int):
+    """Tokens ``xt`` (T, d) routed to ``local`` (T, K) expert ids (this
+    chip's numbering: held experts are 0 .. held - 1) with weights
+    ``gate`` (T, K) -> ((T, d), rows per held expert)."""
+    T, K = local.shape
+    flat = local.reshape(-1)
+    mine = (flat >= 0) & (flat < held)
+    key = jnp.where(mine, flat, held)
+    order = jnp.argsort(key, stable=True)           # held experts first
+    rows = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    m = T * K
+    pad = (-m) % row_tile(m)
+    src = jnp.take(xt, order // K, axis=0)
+    if pad:
+        src = jnp.pad(src, ((0, pad), (0, 0)))
+    y = ops.swiglu_experts(src, *experts, rows)[:m]
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(m))
+    y = jnp.take(y, back, axis=0).reshape(T, K, -1)
+    w = jnp.where(mine, gate.reshape(-1), 0.0).reshape(T, K)
+    out = jnp.einsum("tk,tkd->td", w.astype(y.dtype), y,
+                     preferred_element_type=jnp.float32)
+    return out.astype(xt.dtype), rows

@@ -4,7 +4,8 @@ Training/prefill uses the chunked SSD algorithm (arXiv:2405.21060 §6): the
 sequence is split into chunks; intra-chunk terms are batched GeMMs (exactly
 the balanced 3D-tile case Voltra's GeMM core targets — see DESIGN.md
 §Arch-applicability) and the inter-chunk recurrence is a short scan over
-chunk states. Decode is the O(1) recurrent step.
+chunk states. Decode is the O(1) recurrent step. Both end in Mamba-2's
+gated RMSNorm, norm(y * silu(z)), before the output projection.
 """
 from __future__ import annotations
 
@@ -50,10 +51,16 @@ def _split(cfg, zxbcdt):
     return z, xBC, dt  # dt: (..., nheads)
 
 
+def _gated_norm(cfg, p, y, z):
+    """Mamba-2's gated RMSNorm: the gate before the norm, in float32."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return norm_apply(p["norm"], g, "rmsnorm", cfg.norm_eps).astype(y.dtype)
+
+
 def _conv(cfg, p, xBC):
     """Causal depthwise conv over sequence axis 1."""
     w = cfg.ssm.conv_width
-    out = p["conv_b"] * jnp.ones_like(xBC)
+    out = p["conv_b"]
     for i in range(w):
         shift = w - 1 - i
         xs = jnp.pad(xBC, ((0, 0), (shift, 0), (0, 0)))[:, : xBC.shape[1]]
@@ -126,8 +133,8 @@ def ssm_apply(cfg, p, x, *, rules: Rules = NO_RULES,
     s = cfg.ssm
     d_inner, nheads, conv_dim = dims(cfg)
     zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"])
-    z, xBC, dt = _split(cfg, zxbcdt)
-    xBC = _conv(cfg, p, xBC)
+    z, xBC_raw, dt = _split(cfg, zxbcdt)
+    xBC = _conv(cfg, p, xBC_raw)
     gn = s.num_groups * s.state_dim
     xin, B_, C_ = jnp.split(xBC, [d_inner, d_inner + gn], axis=-1)
     b, l = x.shape[0], x.shape[1]
@@ -143,15 +150,12 @@ def ssm_apply(cfg, p, x, *, rules: Rules = NO_RULES,
     A = -jnp.exp(p["A_log"])
     y, S_final = _ssd_scan(xin, dt_, A, B_, C_, s.chunk)
     y = y + p["D"][None, None, :, None].astype(y.dtype) * xin
-    y = y.reshape(b, l, d_inner)
-    y = norm_apply(p["norm"], y, "rmsnorm") * jax.nn.silu(z)
+    y = _gated_norm(cfg, p, y.reshape(b, l, d_inner), z)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     out = rules.cons(out, "batch,seq,embed")
     if return_state:
         w = cfg.ssm.conv_width
         # conv state: last (w-1) *pre-activation* xBC inputs
-        zxb = jnp.einsum("bsd,de->bse", x, p["in_proj"])
-        _, xBC_raw, _ = _split(cfg, zxb)
         if length is not None:
             from repro.models.griffin import _gather_conv_state
             conv_state = _gather_conv_state(xBC_raw, length, w, l)
@@ -201,8 +205,7 @@ def _ssm_token_step(cfg, p, carry, zxbcdt):
     S = dA[..., None, None] * S_prev + jnp.einsum(
         "bh,bhn,bhp->bhpn", dt_, Bh, xf)
     y = jnp.einsum("bhn,bhpn->bhp", Ch, S) + p["D"][None, :, None] * xf
-    y = y.reshape(bsz, d_inner).astype(zxbcdt.dtype)
-    y = norm_apply(p["norm"], y, "rmsnorm") * jax.nn.silu(z)
+    y = _gated_norm(cfg, p, y.reshape(bsz, d_inner).astype(zxbcdt.dtype), z)
     return (S, new_conv), y
 
 

@@ -5,6 +5,15 @@ head``. A *super-block* is one period of the arch's block pattern (e.g.
 RecurrentGemma: ``(rglru, rglru, local_attn)``); homogeneous params of each
 pattern position are stacked and scanned (small HLO, production-style).
 Layers that don't fit a whole period form an explicitly-applied tail.
+
+Block kinds: ``attn_mlp`` / ``attn_moe`` (attention, then a dense or a
+routed FFN), ``local_attn`` (sliding window + dense FFN), ``rglru``
+(RG-LRU + dense FFN), ``ssm`` (a bare Mamba-2 mixer), ``ssm_moe``
+(Mamba-2 mixer, then a routed FFN: Granite 4.0-H), ``enc`` / ``dec``.
+A routed FFN is the dropping ``moe_apply`` where the MoE config has a
+capacity, else the dropless ``routed_apply`` over the experts held here.
+Every branch is scaled by ``cfg.residual_multiplier`` before its
+residual add.
 """
 from __future__ import annotations
 
@@ -15,8 +24,9 @@ import jax.numpy as jnp
 
 from repro.models import griffin, ssm
 from repro.models.layers import (Maker, attention_apply, attention_decode,
-                                 attention_init, mlp_apply, mlp_init,
-                                 moe_apply, moe_init, norm_apply, norm_init)
+                                 attention_init, is_dropless, mlp_apply,
+                                 mlp_init, moe_apply, moe_init, norm_apply,
+                                 norm_init, routed_apply)
 from repro.parallel.sharding import NO_RULES, Rules
 
 VOCAB_PAD = 256
@@ -64,6 +74,8 @@ def block_init(mk: Maker, cfg, kind: str) -> Dict[str, Any]:
     elif kind == "ssm":
         p["mixer"] = ssm.ssm_init(mk, cfg)
         return p  # pure mamba block: no FFN sublayer
+    elif kind == "ssm_moe":
+        p["mixer"] = ssm.ssm_init(mk, cfg)
     elif kind == "rglru":
         p["mixer"] = griffin.rglru_init(mk, cfg)
     else:
@@ -72,11 +84,37 @@ def block_init(mk: Maker, cfg, kind: str) -> Dict[str, Any]:
         p["lnx"] = norm_init(mk, d, cfg.norm)
         p["cross"] = attention_init(mk, cfg)
     p["ln2"] = norm_init(mk, d, cfg.norm)
-    if kind == "attn_moe":
+    if kind in ("attn_moe", "ssm_moe"):
         p["moe"] = moe_init(mk, cfg)
     else:
         p["mlp"] = mlp_init(mk, cfg)
     return p
+
+
+def _norm(cfg, p, x):
+    return norm_apply(p, x, cfg.norm, cfg.norm_eps)
+
+
+def _res(cfg, x, a):
+    """The residual add of a branch, scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        a = a * cfg.residual_multiplier
+    return x + a
+
+
+def _ffn(cfg, kind, p, x, rules):
+    """The FFN sublayer of a block (norm, FFN, residual add) -> (x, aux,
+    rows): ``rows`` counts the routed rows of each held expert for the
+    dropless routed layer, else None."""
+    h2 = _norm(cfg, p["ln2"], x)
+    aux, rows = {}, None
+    if kind not in ("attn_moe", "ssm_moe"):
+        f = mlp_apply(cfg, p["mlp"], h2, rules=rules)
+    elif is_dropless(cfg):
+        f, rows = routed_apply(cfg, p["moe"], h2, rules=rules)
+    else:
+        f, aux = moe_apply(cfg, p["moe"], h2, rules=rules)
+    return _res(cfg, x, f), aux, rows
 
 
 def _grow(cfg, kv, max_len):
@@ -107,9 +145,8 @@ def block_apply(cfg, kind: str, p, x, *, rules: Rules = NO_RULES,
     return the FULL-sequence kv like full attention (the paged engine
     scatters only the live window blocks into pages) instead of the
     dense engine's window-sized ring buffer."""
-    aux = {}
     cache = None
-    h = norm_apply(p["ln1"], x, cfg.norm)
+    h = _norm(cfg, p["ln1"], x)
     if kind in ("attn_mlp", "attn_moe", "dec"):
         a, kv = attention_apply(cfg, p["attn"], h, rules=rules,
                                 positions=positions, prefix=prefix)
@@ -125,44 +162,37 @@ def block_apply(cfg, kind: str, p, x, *, rules: Rules = NO_RULES,
     elif kind == "enc":
         a, _ = attention_apply(cfg, p["attn"], h, rules=rules,
                                positions=positions, causal=False)
-    elif kind == "ssm":
+    elif kind in ("ssm", "ssm_moe"):
         if want_cache:
             a, cache = ssm.ssm_apply(cfg, p["mixer"], h, rules=rules,
                                      return_state=True, length=length)
         else:
             a = ssm.ssm_apply(cfg, p["mixer"], h, rules=rules)
-        return x + a, cache, aux
+        if kind == "ssm":
+            return _res(cfg, x, a), cache, {}
     elif kind == "rglru":
         if want_cache:
             a, cache = griffin.rglru_apply(cfg, p["mixer"], h, rules=rules,
                                            return_state=True, length=length)
         else:
             a = griffin.rglru_apply(cfg, p["mixer"], h, rules=rules)
-        x = x + a
-        h2 = norm_apply(p["ln2"], x, cfg.norm)
-        x = x + mlp_apply(cfg, p["mlp"], h2, rules=rules)
-        return x, cache, aux
     else:
         raise ValueError(kind)
-    x = x + a
+    x = _res(cfg, x, a)
     if kind == "dec":
-        hx = norm_apply(p["lnx"], x, cfg.norm)
+        hx = _norm(cfg, p["lnx"], x)
         ck = jnp.einsum("bsd,dhe->bshe", enc_out, p["cross"]["wk"])
         cv = jnp.einsum("bsd,dhe->bshe", enc_out, p["cross"]["wv"])
         if cfg.qkv_bias:
             ck, cv = ck + p["cross"]["bk"], cv + p["cross"]["bv"]
         a, _ = attention_apply(cfg, p["cross"], hx, rules=rules,
                                cross_kv=(ck, cv))
-        x = x + a
+        x = _res(cfg, x, a)
         if want_cache:
             from repro.models.layers import kv_quant
             cache = {**cache, "xk": kv_quant(cfg, ck), "xv": kv_quant(cfg, cv)}
-    h2 = norm_apply(p["ln2"], x, cfg.norm)
-    if kind == "attn_moe":
-        f, aux = moe_apply(cfg, p["moe"], h2, rules=rules)
-    else:
-        f = mlp_apply(cfg, p["mlp"], h2, rules=rules)
-    return x + f, cache, aux
+    x, aux, _ = _ffn(cfg, kind, p, x, rules)
+    return x, cache, aux
 
 
 def _window_cache(cfg, kv, w):
@@ -188,52 +218,48 @@ def block_decode(cfg, kind: str, p, x, cache, pos, *,
                  win_block_table=None):
     """Decode block step. x: (B, T, d) — T == 1 for plain decode; paged
     blocks also take T > 1 speculative verify blocks (pos is the first
-    row's position; see layers.attention_decode). Returns (x, new_cache).
+    row's position; see layers.attention_decode). Returns (x, new_cache,
+    rows): ``rows`` counts the routed rows of each held expert of a
+    dropless routed FFN (else None).
     block_table switches the full-attention cache entries to the
     paged-pool layout; win_block_table does the same for local_attn
     layers (sliding-window pages, recycled as they leave the window —
     without it local_attn runs the dense ring buffer, single-token only).
-    Recurrent kinds (ssm/rglru) hold per-slot state, not pages: T > 1
-    runs a T-step recurrence returning checkpointed states (see
+    Recurrent kinds (ssm/ssm_moe/rglru) hold per-slot state, not pages:
+    T > 1 runs a T-step recurrence returning checkpointed states (see
     ssm_decode / rglru_decode)."""
-    h = norm_apply(p["ln1"], x, cfg.norm)
+    h = _norm(cfg, p["ln1"], x)
     if kind in ("attn_mlp", "attn_moe", "dec"):
-        a, cache_a = attention_decode(cfg, p["attn"], h,
-                                      {"k": cache["k"], "v": cache["v"]},
-                                      pos, rules=rules,
-                                      block_table=block_table)
+        a, new_cache = attention_decode(cfg, p["attn"], h,
+                                        {"k": cache["k"], "v": cache["v"]},
+                                        pos, rules=rules,
+                                        block_table=block_table)
     elif kind == "local_attn":
-        a, cache_a = attention_decode(cfg, p["attn"], h,
-                                      {"k": cache["k"], "v": cache["v"]},
-                                      pos, rules=rules,
-                                      window=cfg.hybrid.window,
-                                      block_table=win_block_table)
-    elif kind == "ssm":
+        a, new_cache = attention_decode(cfg, p["attn"], h,
+                                        {"k": cache["k"], "v": cache["v"]},
+                                        pos, rules=rules,
+                                        window=cfg.hybrid.window,
+                                        block_table=win_block_table)
+    elif kind in ("ssm", "ssm_moe"):
         a, new_cache = ssm.ssm_decode(cfg, p["mixer"], h, cache, rules=rules)
-        return x + a, new_cache
+        if kind == "ssm":
+            return _res(cfg, x, a), new_cache, None
     elif kind == "rglru":
         a, new_cache = griffin.rglru_decode(cfg, p["mixer"], h, cache,
                                             rules=rules)
-        x = x + a
-        h2 = norm_apply(p["ln2"], x, cfg.norm)
-        return x + mlp_apply(cfg, p["mlp"], h2, rules=rules), new_cache
     else:
         raise ValueError(kind)
-    x = x + a
-    new_cache = dict(cache_a)
+    x = _res(cfg, x, a)
     if kind == "dec":
-        hx = norm_apply(p["lnx"], x, cfg.norm)
+        new_cache = dict(new_cache)
+        hx = _norm(cfg, p["lnx"], x)
         a, _ = attention_decode(cfg, p["cross"], hx,
                                 {"k": cache["xk"], "v": cache["xv"]},
                                 pos, rules=rules, cross=True)
-        x = x + a
+        x = _res(cfg, x, a)
         new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
-    h2 = norm_apply(p["ln2"], x, cfg.norm)
-    if kind == "attn_moe":
-        f, _ = moe_apply(cfg, p["moe"], h2, rules=rules)
-    else:
-        f = mlp_apply(cfg, p["mlp"], h2, rules=rules)
-    return x + f, new_cache
+    x, _, rows = _ffn(cfg, kind, p, x, rules)
+    return x, new_cache, rows
 
 
 # ---------------------------------------------------------------------------
@@ -329,31 +355,47 @@ def stack_apply(cfg, params, x, kinds, tail, *, rules=NO_RULES,
 
 
 def stack_decode(cfg, params, x, caches, pos, kinds, tail, *, rules=NO_RULES,
-                 block_table=None, win_block_table=None):
+                 block_table=None, win_block_table=None,
+                 expert_rows: bool = False):
     """Decode the whole stack one step. x: (B, T, d); T > 1 (a speculative
     multi-token block) requires every attention layer on a paged cache
     layout (block_table for full attention, win_block_table for sliding
     windows); recurrent layers then return checkpointed per-row states —
-    see block_decode."""
+    see block_decode. ``expert_rows`` adds a third result: the routed
+    rows of each held expert, (routed layers, held) int32, layers in
+    stack order (None where no layer routes dropless)."""
     def body(h, sl):
         pslice, cslice = sl
-        new_c = {}
+        new_c, rows = {}, []
         for j, kd in enumerate(kinds):
-            h, nc = block_decode(cfg, kd, pslice[str(j)], h, cslice[str(j)],
-                                 pos, rules=rules, block_table=block_table,
-                                 win_block_table=win_block_table)
+            h, nc, r = block_decode(cfg, kd, pslice[str(j)], h,
+                                    cslice[str(j)], pos, rules=rules,
+                                    block_table=block_table,
+                                    win_block_table=win_block_table)
             new_c[str(j)] = nc
-        return h, new_c
+            if r is not None:
+                rows.append(r)
+        return h, (new_c, rows)
 
+    rows = []
     n_super = jax.tree.leaves(params["scan"])[0].shape[0] if params["scan"] else 0
     if n_super:
-        x, new_scan = jax.lax.scan(body, x, (params["scan"], caches["scan"]))
+        x, (new_scan, scan_rows) = jax.lax.scan(
+            body, x, (params["scan"], caches["scan"]))
+        if scan_rows:           # (n_super, held) per pattern position
+            rows.append(jnp.stack(scan_rows, 1).reshape(
+                -1, scan_rows[0].shape[-1]))
     else:
         new_scan = {}
     new_tail = []
     for tp, kd, tc in zip(params["tail"], tail, caches["tail"]):
-        x, nc = block_decode(cfg, kd, tp, x, tc, pos, rules=rules,
-                             block_table=block_table,
-                             win_block_table=win_block_table)
+        x, nc, r = block_decode(cfg, kd, tp, x, tc, pos, rules=rules,
+                                block_table=block_table,
+                                win_block_table=win_block_table)
         new_tail.append(nc)
-    return x, {"scan": new_scan, "tail": new_tail}
+        if r is not None:
+            rows.append(r[None])
+    out = (x, {"scan": new_scan, "tail": new_tail})
+    if expert_rows:
+        out += (jnp.concatenate(rows) if rows else None,)
+    return out

@@ -89,6 +89,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.paged_attention import pages_per_block
 from repro.models import api
 from repro.models import transformer as tfm
+from repro.models.layers import is_dropless
 from repro.parallel.sharding import NO_RULES, Rules
 from repro.parallel.tp import tp_plan
 from repro.runtime.drafter import ngram_propose
@@ -99,7 +100,7 @@ from repro.runtime.sampling import (ACCEPT_DRAW, NEG_FILTER, SAMPLE_DRAW,
                                     SamplingParams, draw_keys, fold_keys,
                                     policy_operands, request_params,
                                     sample_rows, scale_mask)
-from repro.runtime.trace import Tracer, default_tracer, percentile
+from repro.runtime.trace import NOOP_SPAN, Tracer, default_tracer, percentile
 
 
 @dataclasses.dataclass
@@ -129,6 +130,17 @@ def _pageable(cfg) -> bool:
     recurrent state (fixed-size slots) are all servable; only
     encoder-decoder stacks fall back to the dense engine."""
     return set(tfm.pattern_for(cfg)) <= set(api.PAGED_SERVABLE_KINDS)
+
+
+def expert_series(rows) -> Dict[str, float]:
+    """The ``experts`` counter's series for one decode step from its
+    routed rows per held expert, (routed layers, held): rows per held
+    expert (mean and max over layers and experts) and the held experts
+    hit per layer (mean). Every slot's row counts, a dead slot's too: the
+    step routes and computes it."""
+    r = np.asarray(rows)
+    return {"rows_mean": float(r.mean()), "rows_max": float(r.max()),
+            "hit": float((r > 0).sum(1).mean())}
 
 
 def _win_rid(rid: int):
@@ -426,6 +438,10 @@ class PagedServingEngine(ServingMetricsMixin):
         self.has_full = bool(present & set(api.PAGEABLE_KINDS))
         self.has_win = bool(present & set(api.WINDOW_KINDS))
         self.has_state = bool(present & set(api.STATE_KINDS))
+        # dropless routed layers: the plain step also returns the routed
+        # rows of each held expert (the tracer's ``experts`` counter)
+        self._routes = (is_dropless(cfg) and mesh is None
+                        and any(k.endswith("_moe") for k in present))
         self.window = cfg.hybrid.window if self.has_win else 0
         if prefix_cache and (self.has_win or self.has_state):
             raise ValueError(
@@ -563,6 +579,12 @@ class PagedServingEngine(ServingMetricsMixin):
         self._select_fn = jax.jit(self._make_select()) \
             if (spec_k and self.has_state) else None
         self._prefill_fn = jax.jit(self._make_prefill())
+        # a finished request's recurrent state is zeroed in place, so a
+        # dead slot decodes from a clean state until the next admission
+        # writes its own (inside the prefill program)
+        self._state_reset_fn = jax.jit(
+            lambda c, s: api.state_slot_reset(cfg, c, s),
+            donate_argnums=0) if self.has_state else None
         self._prefill_shared_fn = jax.jit(self._make_prefill_shared())
         self._cow_fn = jax.jit(self._make_cow())
         # host-tier page IO: kind-filtered gather/scatter pairs (full-attn
@@ -609,26 +631,29 @@ class PagedServingEngine(ServingMetricsMixin):
             in_specs=(self._param_specs, self._cache_specs) + rep,
             out_specs=(P(), self._cache_specs))
 
-    def _decode_call(self):
+    def _decode_call(self, expert_rows: bool = False):
         """The model call both decode-side programs share — the exact
         extent of the TP manual region for a decode step. Works for T=1
         rows (plain step) and T=spec_k+1 blocks (speculative verify): the
         per-shard flash-decode sweep sees its local KV-head slice of the
-        pool and the GQA fold is untouched (kernels/paged_attention.py)."""
+        pool and the GQA fold is untouched (kernels/paged_attention.py).
+        ``expert_rows`` adds the routed rows of each held expert per
+        layer (api.decode_step) as a third result."""
         cfg, rules, has_win = self.cfg, self._model_rules, self.has_win
 
         def call(params, cache, block_table, win_table, tok, pos):
             return api.decode_step(
                 cfg, params, cache, tok, pos, rules=rules,
                 block_table=block_table,
-                win_block_table=win_table if has_win else None)
+                win_block_table=win_table if has_win else None,
+                expert_rows=expert_rows)
 
         return call
 
     def _make_step(self):
         cfg = self.cfg
         eos, max_len = self.eos_id, self.max_len
-        decode = self._wrap_sharded(self._decode_call(), 4)
+        decode = self._wrap_sharded(self._decode_call(self._routes), 4)
 
         def step(params, cache, block_table, win_table, cur_tok, pos, live,
                  gen, max_new, pol):
@@ -641,8 +666,9 @@ class PagedServingEngine(ServingMetricsMixin):
             # a dead slot keeps its last request's position, but its
             # table is all scratch: decode it at position 0, so the
             # attention kernel streams one page for it, not that length
-            logits, cache = decode(params, cache, block_table, win_table,
-                                   cur_tok, jnp.where(live, pos, 0))
+            logits, cache, *rows = decode(params, cache, block_table,
+                                          win_table, cur_tok,
+                                          jnp.where(live, pos, 0))
             toks = sample_rows(logits[..., : cfg.vocab], pol)
             livei = live.astype(jnp.int32)
             pos2 = pos + livei
@@ -651,7 +677,7 @@ class PagedServingEngine(ServingMetricsMixin):
                            | (pos2 >= max_len - 1))
             live2 = live & ~done
             cur2 = jnp.where(live[:, None], toks[:, None], cur_tok)
-            return cache, cur2, pos2, gen2, live2, done, toks
+            return (cache, cur2, pos2, gen2, live2, done, toks, *rows)
 
         return step
 
@@ -1190,7 +1216,8 @@ class PagedServingEngine(ServingMetricsMixin):
             self._prefill_for(bucket)
             tr = self.trace
             with tr.span("prefill_dispatch",
-                         args={"bucket": bucket} if tr else None):
+                         args={"bucket": bucket} if tr else None), \
+                    self._state_span("state_write", slot):
                 (self.cache, self.block_table, self.win_table, self.pos,
                  self.cur_tok, self.live_mask, self.gen_cnt,
                  self.max_new_arr, tok) = self._prefill_fn(
@@ -1291,8 +1318,21 @@ class PagedServingEngine(ServingMetricsMixin):
 
     def _finish_slot(self, slot: int) -> None:
         req = self._release_slot(slot)
+        if self._state_reset_fn is not None:
+            with self._state_span("state_reset", slot):
+                self.cache = self._state_reset_fn(self.cache,
+                                                  jnp.int32(slot))
         req.done = True
         self._note_finished(req.rid)
+
+    def _state_span(self, name: str, slot: int):
+        """A span around the program that writes (``state_write``: the
+        prefill) or zeroes (``state_reset``) a slot's recurrent state;
+        none on stacks without recurrent layers."""
+        tr = self.trace
+        if not (tr and self.has_state):
+            return NOOP_SPAN
+        return tr.span(name, args={"slot": slot})
 
     def _evict_slot(self, slot: int) -> Request:
         """Preempt destructively: reclaim pages, return the request for
@@ -1676,10 +1716,12 @@ class PagedServingEngine(ServingMetricsMixin):
         t0 = time.perf_counter()
         with tr.span("device_dispatch"):
             (self.cache, self.cur_tok, self.pos, self.gen_cnt,
-             self.live_mask, done_d, toks_d) = self._step_fn(*operands)
+             self.live_mask, done_d, toks_d, *rows_d) = self._step_fn(
+                *operands)
         with tr.span("host_sync"):
             # repro-lint: disable=host-sync — THE one blessed sync per step
-            toks, done = jax.device_get((toks_d, done_d))
+            toks, done, rows = jax.device_get(
+                (toks_d, done_d, rows_d if tr else []))
         self.step_wall_s += time.perf_counter() - t0
         self.decode_steps += 1
         with tr.span("emit"):
@@ -1707,6 +1749,8 @@ class PagedServingEngine(ServingMetricsMixin):
                     "allocated": float(self.alloc.allocated_pages),
                     "free": float(self.alloc.free_pages),
                     "live": float(live), **series})
+                if rows:
+                    tr.counter("experts", expert_series(rows[0]))
         return evicted
 
     def _step_speculative(self) -> List[Request]:

@@ -37,13 +37,17 @@ Design constraints, in order:
 Span taxonomy (tids group the timeline rows; see DESIGN.md
 "Observability" for the full map):
 
-* tid ``engine``  — ``admit`` (prefill path: ``prefill_dispatch``,
-  ``prefill_sync``), ``decode_tick`` (``tier_drain``,
+* tid ``engine``  — ``admit`` (prefill path: ``prefill_dispatch``, with
+  ``state_write`` inside it where the stack has recurrent layers,
+  ``prefill_sync``), ``state_reset`` (a finished slot's recurrent state
+  zeroed), ``decode_tick`` (``tier_drain``,
   ``ensure_capacity``, ``draft``, ``operands``, ``device_dispatch``,
   ``host_sync``, ``emit`` / ``accept_rollback``), ``swap_in`` /
   ``swap_out`` / ``promote_match``; counter ``pool_pages`` (allocated,
   free, live: the slots the step ran; kv_blocks: the KV blocks the
-  attention kernel streamed per layer).
+  attention kernel streamed per layer); counter ``experts`` where the
+  stack has dropless routed layers (rows_mean, rows_max: routed rows
+  per held expert in the step; hit: held experts with rows, per layer).
 * tid ``sched``   — ``tick`` (``admit_loop``, ``prefetch``).
 * tid ``tier``    — ``d2h_finalize``, ``h2d_demand_fetch`` (the copy-
   stream *stall*: a consumer whose prefetch never started), instants

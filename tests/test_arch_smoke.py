@@ -102,6 +102,7 @@ def test_full_config_matches_assignment(arch):
         "internvl2-2b": (24, 2048, 16, 8, 8192, 92553),
         "mamba2-2.7b": (64, 2560, None, None, 0, 50280),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
     }[arch]
     cfg = get_config(arch)
     L, d, h, kv, ff, v = spec

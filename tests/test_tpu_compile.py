@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import attention, gemm_os, paged_attention
+from repro.kernels import attention, experts, gemm_os, paged_attention
 
 PAGE = 16
 BATCH = 8
@@ -72,6 +72,15 @@ def _paged_case(arch, T, pool_dtype=jnp.bfloat16, window=0):
                 ((BATCH, N_BLOCKS), jnp.int32), ((BATCH,), jnp.int32)]
 
 
+def _experts_case(rows, held=9, d=4096, f=768):
+    def fn(x, wg, wi, wo, sizes):
+        return experts.swiglu_experts(x, wg, wi, wo, sizes, False)
+
+    return fn, [((rows, d), jnp.bfloat16), ((held, d, f), jnp.bfloat16),
+                ((held, d, f), jnp.bfloat16), ((held, f, d), jnp.bfloat16),
+                ((held,), jnp.int32)]
+
+
 CASES = {
     # qwen2.5-3b decode: 16 q heads over 2 KV heads x 128 dims
     "paged-qwen-T1": lambda: _paged_case("qwen2.5-3b", 1),
@@ -90,6 +99,11 @@ CASES = {
         lambda x, w: gemm_os.gemm_os(x, w, quant_scale=0.01,
                                      interpret=False),
         [((2048, 2048), jnp.int8), ((2048, 11008), jnp.int8)]),
+    # granite-4.0-h-small's held experts (9 of 72, d 4096, width 768):
+    # a decode step's 24 x 10 routed rows (padded to the 32-row tile),
+    # and a 2048-token prefill chunk's 20480
+    "experts-decode": lambda: _experts_case(256),
+    "experts-prefill": lambda: _experts_case(20480),
     # prefill-shaped flash MHA with a traced per-sequence valid length
     "mha-kv-valid": lambda: (
         lambda q, k, v, n: attention.mha(q, k, v, kv_valid=n,
