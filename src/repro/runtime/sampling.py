@@ -14,6 +14,21 @@ The contract that makes per-request policies free at trace time:
   with t <= 0 multiply the noise by zero and reduce to the exact argmax
   the pre-ISSUE-9 engine computed (top-k/top-p masks always keep the
   top-1 token, so they never perturb a greedy row).
+* **Stages run only when some row of the batch asks for them.** The
+  top-k stage (a full-vocabulary sort and mask), the top-p stage
+  (another sort, softmax, cumsum and mask) and the Gumbel draw each sit
+  under a ``lax.cond`` on a batch-wide predicate of the policy operands
+  (``any(top_k > 0)``, ``any(top_p < 1)``, ``any(temp > 0)``), decided
+  on the device: one trace still serves every policy mix, and an
+  all-greedy batch is a plain ``argmax``. Skipping is exact, not an
+  approximation: a stage whose predicate is false would have been the
+  identity on every row (each row's own exemption in the ``where``
+  keeps all its entries, and zero times finite noise adds zero), so
+  every token, greedy or sampled, is bit-identical to running all three
+  stages always. The conds stay outside any ``vmap``, where they would
+  become selects that run both branches. ``sampler_stages`` is the
+  host's copy of the three predicates (the engine's ``sampler`` trace
+  counter).
 * **Per-request PRNG, position-indexed.** The key for the draw that
   decides generated token ``idx`` of request ``rid`` is
   ``fold_in(fold_in(fold_in(key(seed), rid), idx), draw)`` — a pure
@@ -45,7 +60,7 @@ verification it generalizes.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -146,24 +161,20 @@ def draw_keys(keys, draw: int) -> jax.Array:
     return jax.vmap(lambda k: jax.random.fold_in(k, draw))(keys)
 
 
-def scale_mask(logits, temp, top_k, top_p) -> jax.Array:
-    """Temperature-scale then top-k/top-p-filter a (B, V) logit batch,
-    rowwise. Returns f32 ``z`` with filtered entries at ``NEG_FILTER``;
-    ``softmax(z)`` is the policy's target distribution p and
-    ``argmax(z)`` its greedy token. Rows with temp <= 0 skip the scale
-    (argmax is scale-invariant and both masks keep the top-1 token, so
-    greedy rows are exact argmax rows regardless of k/p)."""
-    V = logits.shape[-1]
-    z = logits.astype(jnp.float32)
-    z = z / jnp.where(temp > 0, temp, 1.0)[:, None]
-    # top-k: value threshold at the k-th largest, rows with k<=0 exempt
+def _top_k_mask(z, top_k):
+    """Keep each row's ``top_k`` largest entries (value threshold at the
+    k-th largest); rows with k <= 0 keep everything."""
+    V = z.shape[-1]
     srt = jnp.sort(z, axis=-1)[..., ::-1]
     kth = jnp.take_along_axis(
         srt, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
-    z = jnp.where((z >= kth) | (top_k <= 0)[:, None], z, NEG_FILTER)
-    # top-p (nucleus) on the top-k survivors: keep the smallest sorted
-    # prefix whose cumulative mass reaches p (the token that crosses the
-    # boundary is kept: cum - prob < p)
+    return jnp.where((z >= kth) | (top_k <= 0)[:, None], z, NEG_FILTER)
+
+
+def _top_p_mask(z, top_p):
+    """Nucleus cut: keep the smallest sorted prefix whose cumulative mass
+    reaches p (the token that crosses the boundary is kept: cum - prob <
+    p); rows with p >= 1 keep everything."""
     srt = jnp.sort(z, axis=-1)[..., ::-1]
     probs = jax.nn.softmax(srt, axis=-1)
     keep_sorted = (jnp.cumsum(probs, axis=-1) - probs) < top_p[:, None]
@@ -172,16 +183,36 @@ def scale_mask(logits, temp, top_k, top_p) -> jax.Array:
     return jnp.where((z >= pth) | (top_p >= 1.0)[:, None], z, NEG_FILTER)
 
 
+def scale_mask(logits, temp, top_k, top_p) -> jax.Array:
+    """Temperature-scale then top-k/top-p-filter a (B, V) logit batch,
+    rowwise. Returns f32 ``z`` with filtered entries at ``NEG_FILTER``;
+    ``softmax(z)`` is the policy's target distribution p and
+    ``argmax(z)`` its greedy token. Rows with temp <= 0 skip the scale
+    (argmax is scale-invariant and both masks keep the top-1 token, so
+    greedy rows are exact argmax rows regardless of k/p). Each filter
+    runs only if some row sets it (module docstring)."""
+    z = logits.astype(jnp.float32)
+    z = z / jnp.where(temp > 0, temp, 1.0)[:, None]
+    # top-k first, then top-p on the top-k survivors
+    z = jax.lax.cond(jnp.any(top_k > 0), _top_k_mask,
+                     lambda z, _: z, z, top_k)
+    return jax.lax.cond(jnp.any(top_p < 1.0), _top_p_mask,
+                        lambda z, _: z, z, top_p)
+
+
 def gumbel_argmax(z, temp, keys) -> jax.Array:
     """The fused categorical-or-greedy draw: per-row Gumbel noise is
     zeroed where temp <= 0, so ``argmax(z + noise)`` is a categorical
     sample from softmax(z) on sampled rows and the exact argmax on
-    greedy rows — one program, no branch, no retrace."""
-    g = jax.vmap(lambda k: jax.random.gumbel(
-        k, z.shape[-1:], jnp.float32))(keys)
-    return jnp.argmax(
-        z + jnp.where(temp > 0, 1.0, 0.0)[:, None] * g,
-        axis=-1).astype(jnp.int32)
+    greedy rows — one program, no retrace. The noise is drawn only if
+    some row samples; an all-greedy batch is ``argmax(z)``."""
+    def noisy(z):
+        g = jax.vmap(lambda k: jax.random.gumbel(
+            k, z.shape[-1:], jnp.float32))(keys)
+        return z + jnp.where(temp > 0, 1.0, 0.0)[:, None] * g
+
+    z = jax.lax.cond(jnp.any(temp > 0), noisy, lambda z: z, z)
+    return jnp.argmax(z, axis=-1).astype(jnp.int32)
 
 
 def sample_rows(logits, pol, offset: int = 0) -> jax.Array:
@@ -201,6 +232,18 @@ def request_params(req, default: SamplingParams) -> SamplingParams:
     else the engine default — validated either way."""
     p = getattr(req, "params", None)
     return (p if p is not None else default).validate()
+
+
+def sampler_stages(policies: Sequence[Optional[SamplingParams]]
+                   ) -> Dict[str, float]:
+    """Which of the sampler's gated stages a batch of slot policies runs
+    (1.0) or skips (0.0): the host's copy of the device predicates in
+    ``scale_mask`` and ``gumbel_argmax``. Dead slots (None) are greedy
+    rows, as in ``policy_operands``."""
+    live = [p if p is not None else GREEDY for p in policies]
+    return {"topk": float(any(p.top_k > 0 for p in live)),
+            "topp": float(any(p.top_p < 1.0 for p in live)),
+            "noise": float(any(p.temperature > 0 for p in live))}
 
 
 def summarize(policies: List[Optional[SamplingParams]]) -> str:

@@ -99,7 +99,7 @@ from repro.runtime.prefix_cache import PrefixCache, PrefixMatch
 from repro.runtime.sampling import (ACCEPT_DRAW, NEG_FILTER, SAMPLE_DRAW,
                                     SamplingParams, draw_keys, fold_keys,
                                     policy_operands, request_params,
-                                    sample_rows, scale_mask)
+                                    sample_rows, sampler_stages, scale_mask)
 from repro.runtime.trace import NOOP_SPAN, Tracer, default_tracer, percentile
 
 
@@ -1726,6 +1726,10 @@ class PagedServingEngine(ServingMetricsMixin):
         self.decode_steps += 1
         with tr.span("emit"):
             live, series = 0, {}
+            if tr:
+                # the sampler stages the step ran, from the policies it
+                # ran with (finishing a slot below clears its policy)
+                tr.counter("sampler", sampler_stages(self._policy))
             if tr and self._kv_block_tokens:
                 # blocks each layer's kernel streamed: cdiv(pos + 1, block)
                 # per live slot, at the positions the step ran

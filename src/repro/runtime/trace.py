@@ -47,7 +47,9 @@ Span taxonomy (tids group the timeline rows; see DESIGN.md
   free, live: the slots the step ran; kv_blocks: the KV blocks the
   attention kernel streamed per layer); counter ``experts`` where the
   stack has dropless routed layers (rows_mean, rows_max: routed rows
-  per held expert in the step; hit: held experts with rows, per layer).
+  per held expert in the step; hit: held experts with rows, per layer);
+  counter ``sampler`` (topk, topp, noise: 1.0 where the step ran that
+  gated sampler stage, ``runtime/sampling.py``).
 * tid ``sched``   — ``tick`` (``admit_loop``, ``prefetch``).
 * tid ``tier``    — ``d2h_finalize``, ``h2d_demand_fetch`` (the copy-
   stream *stall*: a consumer whose prefetch never started), instants

@@ -11,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.runtime.sampling import (GREEDY, NEG_FILTER, SamplingParams,
+from repro.runtime.sampling import (GREEDY, NEG_FILTER, SAMPLE_DRAW,
+                                    SamplingParams, draw_keys, fold_keys,
                                     policy_operands, sample_rows,
-                                    scale_mask, summarize)
+                                    sampler_stages, scale_mask, summarize)
 
 # ---------------------------------------------------------------------------
 # SamplingParams (pure host-side)
@@ -89,6 +90,107 @@ def test_greedy_rows_are_exact_argmax():
         rids=[0, 1, 2, 3, 4], idxs=[0, 5, 9, 2, 7], default_seed=0)
     toks = np.asarray(sample_rows(jnp.asarray(logits), pol))
     assert toks.tolist() == np.argmax(logits, -1).tolist()
+
+
+# ---------------------------------------------------------------------------
+# policy-gated stages: exact against the sort-always formulation
+# ---------------------------------------------------------------------------
+
+
+def _sort_always_scale_mask(logits, temp, top_k, top_p):
+    # the sampler before its stages were gated: both sorts on every call
+    V = logits.shape[-1]
+    z = logits.astype(jnp.float32)
+    z = z / jnp.where(temp > 0, temp, 1.0)[:, None]
+    srt = jnp.sort(z, axis=-1)[..., ::-1]
+    kth = jnp.take_along_axis(
+        srt, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
+    z = jnp.where((z >= kth) | (top_k <= 0)[:, None], z, NEG_FILTER)
+    srt = jnp.sort(z, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(srt, axis=-1)
+    keep_sorted = (jnp.cumsum(probs, axis=-1) - probs) < top_p[:, None]
+    n_keep = jnp.maximum(keep_sorted.sum(-1), 1)
+    pth = jnp.take_along_axis(srt, (n_keep - 1)[:, None], axis=-1)
+    return jnp.where((z >= pth) | (top_p >= 1.0)[:, None], z, NEG_FILTER)
+
+
+def _sort_always_sample_rows(logits, pol, offset=0):
+    # ... and the Gumbel noise drawn on every call, zeroed on greedy rows
+    z = _sort_always_scale_mask(logits, pol["temp"], pol["top_k"],
+                                pol["top_p"])
+    keys = draw_keys(
+        fold_keys(pol["seed"], pol["rid"], pol["idx"] + offset),
+        SAMPLE_DRAW)
+    g = jax.vmap(lambda k: jax.random.gumbel(
+        k, z.shape[-1:], jnp.float32))(keys)
+    return jnp.argmax(
+        z + jnp.where(pol["temp"] > 0, 1.0, 0.0)[:, None] * g,
+        axis=-1).astype(jnp.int32)
+
+
+_S = SamplingParams
+GATE_CASES = {
+    "all_greedy": [GREEDY, None, GREEDY, GREEDY, None, GREEDY],
+    "greedy_with_filters": [_S(top_k=5), _S(top_p=0.5), GREEDY,
+                            _S(top_k=3, top_p=0.8), None, _S(top_k=1)],
+    "temperature_only": [_S(temperature=t, seed=40 + i) for i, t in
+                         enumerate([0.3, 0.7, 1.0, 1.5, 2.0, 0.9])],
+    "top_k_only": [_S(temperature=1.0, top_k=k, seed=50 + k)
+                   for k in (1, 2, 8, 40, 999, 5000)],
+    "top_p_only": [_S(temperature=0.8, top_p=p, seed=60 + i)
+                   for i, p in enumerate([0.1, 0.5, 0.9, 0.99, 1.0, 0.3])],
+    "mixed": [GREEDY, _S(temperature=0.9, top_k=20, top_p=0.9, seed=3),
+              None, _S(temperature=1.2), _S(top_p=0.6),
+              _S(temperature=0.5, top_k=4)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(GATE_CASES))
+def test_gated_stages_bit_identical_to_sort_always(case):
+    """Each sampler stage runs only when some row asks for it; skipping
+    is exact, so tokens and masked logits equal the sort-always program
+    bit for bit, for every policy mix (bf16 logits: ties included)."""
+    policies = GATE_CASES[case]
+    rng = np.random.default_rng(sorted(GATE_CASES).index(case))
+    logits = jnp.asarray(3 * rng.normal(size=(len(policies), 1000)),
+                         jnp.bfloat16)
+    args = (logits, *[policy_operands(policies, [0] * 6, [0] * 6, 0)[k]
+                      for k in ("temp", "top_k", "top_p")])
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(scale_mask)(*args)),
+        np.asarray(jax.jit(_sort_always_scale_mask)(*args)))
+    for idxs in ([0] * 6, [0, 3, 17, 250, 9, 1], [7, 7, 4096, 2, 31, 64]):
+        pol = policy_operands(policies, rids=[5, 1, 2, 3, 4, 0],
+                              idxs=idxs, default_seed=11)
+        got = jax.jit(sample_rows)(logits, pol)
+        want = jax.jit(_sort_always_sample_rows)(logits, pol)
+        assert np.asarray(got).tolist() == np.asarray(want).tolist(), idxs
+
+
+def _sorts(jaxpr, under_cond=False):
+    """(sort, under a cond?) for every sort in a jaxpr, nested included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            out.append(under_cond)
+        inner = under_cond or eqn.primitive.name == "cond"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _sorts(sub, inner)
+    return out
+
+
+def test_sampler_sorts_only_inside_cond_branches():
+    """No sort runs unconditionally: a later edit that puts a
+    full-vocabulary sort back on the all-greedy path fails here."""
+    pol = policy_operands([GREEDY, _S(temperature=1.0, top_k=3, top_p=0.5)],
+                          rids=[0, 1], idxs=[0, 0], default_seed=0)
+    jaxpr = jax.make_jaxpr(sample_rows)(jnp.zeros((2, 64)), pol).jaxpr
+    assert "sort" not in [e.primitive.name for e in jaxpr.eqns]
+    sorts = _sorts(jaxpr)
+    assert len(sorts) == 2 and all(sorts), sorts
 
 
 # ---------------------------------------------------------------------------
@@ -207,6 +309,45 @@ def test_mixed_policy_batch_compiles_one_step_trace(qwen):
     # near-uniform smoke logits: sampling at temp 0.8 diverges somewhere
     assert any(r.generated != b.generated
                for b, r in zip(base, mixed) if r.params is not None)
+
+
+def test_step_records_sampler_stages_counter(qwen):
+    """With a tracer on, each decode step records which sampler stages
+    it ran: none for an all-greedy batch; top-p and the noise exactly
+    while one top-p sampled request is live."""
+    from repro.runtime.serving import PagedServingEngine
+    from repro.runtime.trace import Tracer
+    cfg, params = qwen
+
+    def engine():
+        tr = Tracer(enabled=True)
+        return tr, PagedServingEngine(cfg, params, slots=4, max_len=32,
+                                      page_size=8, attn_impl="gather",
+                                      tracer=tr)
+
+    def samples(tr):
+        return [e["args"] for e in tr.events() if e["name"] == "sampler"]
+
+    tr, eng = engine()
+    eng.run_to_completion(_mk_reqs())
+    assert len(samples(tr)) == eng.decode_steps > 0
+    assert all(s == {"topk": 0.0, "topp": 0.0, "noise": 0.0}
+               for s in samples(tr))
+
+    tr, eng = engine()
+    reqs = _mk_reqs()
+    reqs[1].max_new = 3
+    reqs[1].params = SamplingParams(temperature=0.8, top_p=0.9, seed=5)
+    for r in reqs:
+        assert eng.submit(r)
+    seen = []
+    while eng.has_live():
+        on = float(any(r is reqs[1] for r in eng.live))
+        eng.step()
+        assert samples(tr)[-1] == {"topk": 0.0, "topp": on, "noise": on}
+        seen.append(on)
+    assert 0.0 in seen and 1.0 in seen
+    assert sampler_stages([None, reqs[1].params]) == samples(tr)[0]
 
 
 def test_dense_mixed_policy_trace_count_is_mix_invariant(qwen):
